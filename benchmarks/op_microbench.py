@@ -41,7 +41,7 @@ import jax.numpy as jnp
 
 from repro.core import pq, quant
 from repro.kernels import autotune, measure, ops
-from repro.roofline.analysis import HBM_BW, PEAK_FLOPS
+from repro.roofline.analysis import chip_peaks
 
 OPS = [
     # (name, N, D, M, K, V)
@@ -145,8 +145,11 @@ def bench_op(name: str, n: int, d: int, m: int, k: int, v: int) -> dict:
     lut_bytes_ = c * k * m + c * k * v * 4 + (n * d + n * m) * 2
     dense_flops_ = 2 * n * d * m
     lut_flops_ = 2 * n * d * k + 2 * n * c * k * m   # one-hot MXU path
-    t_roof_dense = max(dense_bytes_ / HBM_BW, dense_flops_ / PEAK_FLOPS) * 1e6
-    t_roof_lut = max(lut_bytes_ / HBM_BW, lut_flops_ / PEAK_FLOPS) * 1e6
+    peaks = chip_peaks()
+    t_roof_dense = max(dense_bytes_ / peaks.hbm_bw,
+                       dense_flops_ / peaks.bf16_flops) * 1e6
+    t_roof_lut = max(lut_bytes_ / peaks.hbm_bw,
+                     lut_flops_ / peaks.bf16_flops) * 1e6
 
     return {
         "op": name,

@@ -97,8 +97,8 @@ def lut_linear(
             table, bits=cfg.bits, per_column=cfg.per_column, m_shared=cfg.int8_dot
         )
         xf, lead = _flatten_lead(x)
-        dists = pq.pairwise_sq_dists(pq.split_subvectors(xf, cfg.v), P)
-        enc = pq.ste_encode(dists, t)
+        scores = pq.encode_scores(pq.split_subvectors(xf, cfg.v), P)
+        enc = pq.ste_encode(scores, t)
         y = pq.lut_contract(enc.astype(x.dtype), table.astype(x.dtype))
         b = frozen.get("b")
         y = y + b.astype(y.dtype) if b is not None else y
@@ -120,13 +120,12 @@ def lut_linear(
             # LUT site runs whichever kernel actually wins on its shape.
             y = ops.lut_amm(xf, P, qt.q, qt.scale, bias=b)
         else:
+            scores = pq.encode_scores(pq.split_subvectors(xf, cfg.v), P)
             if cfg.int8_dot:
-                dists = pq.pairwise_sq_dists(pq.split_subvectors(xf, cfg.v), P)
-                y = pq.lut_contract_int8(pq.hard_encode(dists), qt.q, qt.scale)
+                y = pq.lut_contract_int8(pq.hard_encode(scores), qt.q, qt.scale)
             else:
                 table = qt.dequant(dtype=x.dtype)
-                dists = pq.pairwise_sq_dists(pq.split_subvectors(xf, cfg.v), P)
-                enc = pq.hard_encode(dists).astype(x.dtype)
+                enc = pq.hard_encode(scores).astype(x.dtype)
                 y = pq.lut_contract(enc, table)
             y = y + b.astype(y.dtype) if b is not None else y
         return y.reshape(*lead, -1).astype(x.dtype)

@@ -27,6 +27,27 @@ def split_subvectors(a: jax.Array, V: int) -> jax.Array:
     return a.reshape(*lead, D // V, V)
 
 
+def encode_scores(a_sub: jax.Array, P: jax.Array) -> jax.Array:
+    """Centroid scores ||P||^2 - 2 a.P: the squared distance less ||a||^2.
+
+    a_sub: (N, C, V), P: (C, K, V) -> (N, C, K) in fp32. ||a||^2 is the same
+    for every centroid of a codebook, so the argmin (hard encode) and the
+    softmax over -scores/t (soft encode) equal those of the distances. The
+    encoders use these, not the distances: at activations of norm ~10^2 the
+    ||a||^2 term rounds the distances in fp32 to steps coarser than the gaps
+    between centroids, and two fp32 implementations (the TPU kernels, XLA on
+    the CPU) then break the resulting ties differently.
+    """
+    a32 = a_sub.astype(jnp.float32)
+    p32 = P.astype(jnp.float32)
+    # (N, C, K) <- contract V;  batched over codebook axis C. HIGHEST: the
+    # TPU's default is one bf16 pass, whose rounding flips argmin codes.
+    cross = jnp.einsum("ncv,ckv->nck", a32, p32,
+                       precision=jax.lax.Precision.HIGHEST)
+    p_nrm = jnp.sum(p32 * p32, axis=-1)[None, :, :]          # (1, C, K)
+    return p_nrm - 2.0 * cross
+
+
 def pairwise_sq_dists(a_sub: jax.Array, P: jax.Array) -> jax.Array:
     """Squared Euclidean distances between sub-vectors and centroids.
 
@@ -34,12 +55,8 @@ def pairwise_sq_dists(a_sub: jax.Array, P: jax.Array) -> jax.Array:
     ||a||^2 - 2 a.P + ||P||^2 expansion so the inner term maps onto the MXU.
     """
     a32 = a_sub.astype(jnp.float32)
-    p32 = P.astype(jnp.float32)
-    # (N, C, K) <- contract V;  batched over codebook axis C.
-    cross = jnp.einsum("ncv,ckv->nck", a32, p32)
     a_nrm = jnp.sum(a32 * a32, axis=-1)[:, :, None]          # (N, C, 1)
-    p_nrm = jnp.sum(p32 * p32, axis=-1)[None, :, :]          # (1, C, K)
-    return a_nrm - 2.0 * cross + p_nrm
+    return a_nrm + encode_scores(a_sub, P)
 
 
 def hard_encode(dists: jax.Array) -> jax.Array:
@@ -129,7 +146,7 @@ def lut_contract_int8(
 def encode_indices(a: jax.Array, P: jax.Array) -> jax.Array:
     """Inference-side encoder g^c: (N, D) -> int32 (N, C) centroid indices."""
     a_sub = split_subvectors(a, P.shape[-1])
-    return jnp.argmin(pairwise_sq_dists(a_sub, P), axis=-1).astype(jnp.int32)
+    return jnp.argmin(encode_scores(a_sub, P), axis=-1).astype(jnp.int32)
 
 
 def gather_lut(idx: jax.Array, T: jax.Array) -> jax.Array:
@@ -148,6 +165,6 @@ def gather_lut(idx: jax.Array, T: jax.Array) -> jax.Array:
 def pq_reconstruct(a: jax.Array, P: jax.Array) -> jax.Array:
     """Quantize-dequantize a through its nearest centroids (analysis util)."""
     a_sub = split_subvectors(a, P.shape[-1])
-    enc = hard_encode(pairwise_sq_dists(a_sub, P))          # (N, C, K)
+    enc = hard_encode(encode_scores(a_sub, P))              # (N, C, K)
     rec = jnp.einsum("nck,ckv->ncv", enc.astype(P.dtype), P)
     return rec.reshape(a.shape)

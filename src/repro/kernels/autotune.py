@@ -30,7 +30,8 @@ Tuning modes:
     compiled runs, warmup + median-of-k).
   * analytic  — no accelerator present: candidates are scored with the
     roofline model in `predict_us` (HBM traffic / compute / per-step
-    overhead), using the v5e constants from repro.roofline.analysis.
+    overhead), using the attached chip's peaks from
+    `repro.roofline.analysis.chip_peaks` (the v5e's off the chip).
 
 Winners persist to an on-disk JSON cache (DESIGN.md §3.2) keyed by
 (kind, N, M, C, K, V, dtype, backend) and are consumed by `lut_amm_pallas`,
@@ -52,17 +53,16 @@ import pathlib
 import tempfile
 from typing import Any, Callable, Iterator
 
-from repro.roofline.analysis import HBM_BW, PEAK_FLOPS
+from repro.roofline.analysis import ChipPeaks, chip_peaks
 
 # ---------------------------------------------------------------------------
-# hardware model constants (DESIGN.md §3.1)
+# hardware model constants (DESIGN.md §3.1); the chip's peaks come from
+# repro.roofline.analysis.chip_peaks, keyed by device kind
 # ---------------------------------------------------------------------------
 
-VMEM_BYTES = 16 * 2**20          # per-core VMEM (v4/v5 generations)
+VMEM_BYTES = 16 * 2**20          # scoped VMEM a v5e kernel may use
 VMEM_BUDGET = 12 * 2**20         # usable budget: leave headroom for spills
-MXU_F32 = PEAK_FLOPS             # dense fp32/bf16 MXU rate (paper constants)
-MXU_I8 = 2 * PEAK_FLOPS          # int8 MXU rate: 2x the bf16 rate on v5e
-VMEM_BW = 8 * HBM_BW             # rough on-chip bandwidth for VPU passes
+VMEM_BW_PER_HBM_BW = 8           # rough on-chip bandwidth for VPU passes
 STEP_OVERHEAD_S = 1e-6           # fixed per-grid-step cost (DMA setup, sync)
 
 _CACHE_VERSION = 1
@@ -107,36 +107,68 @@ def _divisors(c: int) -> list[int]:
 # VMEM budget model (DESIGN.md §3.1)
 # ---------------------------------------------------------------------------
 
+_LANES = 128
+
+
+def _tile_bytes(shape: tuple[int, ...], itemsize: int) -> int:
+    """VMEM bytes of one array as Mosaic lays it out: the minor dim padded
+    to the 128 lanes, the second-minor to the sublane tile (8 rows of 32-bit
+    words: 8 for fp32/int32, 16 for bf16, 32 for int8)."""
+    *lead, rows, cols = shape
+    sub = 8 * 4 // itemsize
+    n = -(-rows // sub) * sub * (-(-cols // _LANES) * _LANES) * itemsize
+    for d in lead:
+        n *= d
+    return n
+
+
 def vmem_bytes(
     bn: int, bm: int, bc: int, k: int, v: int, *, kind: str = "lut_amm"
 ) -> int:
-    """Per-step VMEM working set of the fused kernel at one tiling.
+    """Per-step VMEM working set of one kernel at one tiling, in padded
+    tile bytes (`_tile_bytes`): with V=32 and K=16 on the lane axis a tile
+    takes 4x and 8x its element count.
 
-    Input tiles are charged twice (the pipeline emitter double-buffers HBM
-    streams); the scratch accumulator and the output tile are single-buffered
-    because their BlockSpec index maps ignore the innermost grid axis.
+    Every block is charged twice, inputs and output alike: the pipeline
+    emitter double-buffers each HBM stream. On top come the scratch buffers
+    and the encode temporaries that live in VMEM while the body runs: an
+    fp32 activation tile and the (bc, bn, K) fp32 distances. Activations
+    are charged as fp32, which bounds bf16; scales as the m-shared
+    (1, 1, M) layout that `deploy_params` gives every kernel site.
 
     kind="fused" (DESIGN.md §13.1): bc must equal C — the fused decode
     kernel keeps the whole codebook axis resident so the encode runs once
-    per N tile. Its working set adds the int8 code scratch (bn·C·K) and a
-    contraction temporary, but drops the per-step accumulator (each output
-    tile is written in a single grid step).
+    per N tile. It adds the int8 code scratch and the (bn, bm) int32
+    contraction result, and has no accumulator.
+
+    Checked against the v5e compiler's own scoped-VMEM sizes on 54 tilings
+    of v1/v2/v3 (AOT compile for a described chip): it over-counts by
+    1.3-7.7x and never under-counts, so a tiling under `VMEM_BUDGET`
+    compiles; tests/test_tpu_compile.py guards the qwen3_1p7b sites.
     """
-    x_tile = bn * bc * v * 4                 # fp32 activations
-    p_tile = bc * k * v * 4                  # fp32 codebook
+    x_tile = _tile_bytes((bn, bc, v), 4)
+    p_tile = _tile_bytes((bc, k, v), 4)                  # fp32 codebook
+    encode_tmp = _tile_bytes((bn, bc, v), 4) + _tile_bytes((bc, bn, k), 4)
     if kind == "encode":
-        out = bn * bc * 4                    # int32 indices
-        return 2 * (x_tile + p_tile) + out
-    t_tile = bc * k * bm                     # int8 table — stays int8 (v2)
-    s_tile = bc * bm * 4                     # scale tile upper bound
-    b_tile = bm * 4                          # fused bias row
-    out = bn * bm * 4                        # fp32 output tile
+        out = _tile_bytes((bn, bc), 4)                   # int32 indices
+        return 2 * (x_tile + p_tile + out) + encode_tmp
+    t_tile = _tile_bytes((bc, k, bm), 1)                 # int8 table
+    s_tile = _tile_bytes((1, 1, bm), 4)                  # m-shared scale row
+    b_tile = _tile_bytes((1, bm), 4)                     # fused bias row
+    out = _tile_bytes((bn, bm), 4)                       # fp32 output tile
+    blocks = 2 * (x_tile + p_tile + t_tile + s_tile + b_tile + out)
     if kind == "fused":
-        codes = bn * bc * k                  # int8 one-hot scratch (all of C)
-        tmp = bn * bm * 8                    # int32 + fp32 contraction temp
-        return 2 * (x_tile + t_tile) + p_tile + s_tile + b_tile + codes + out + tmp
-    acc = bn * bm * 4                        # int32/f32 scratch accumulator
-    return 2 * (x_tile + p_tile + t_tile + s_tile + b_tile) + acc + out
+        codes = _tile_bytes((bn, bc, k), 1)              # int8 one-hot scratch
+        return blocks + codes + encode_tmp + out         # + int32 result
+    acc = out                                            # (bn, bm) scratch
+    return blocks + acc + encode_tmp
+
+
+def _legal_bc(bc: int, c: int, kind: str) -> bool:
+    """bc is the second-minor dim of the (bn, bc, V) activation block: a
+    multiple of the 8-row sublane tile, or the whole codebook axis. The
+    encode kernel's (bn, bc) index output puts it on the 128 lanes too."""
+    return bc % (_LANES if kind == "encode" else 8) == 0 or bc == c
 
 
 # ---------------------------------------------------------------------------
@@ -149,8 +181,10 @@ def predict_us(
     bn: int, bm: int, bc: int,
     *,
     version: int = 2,
+    peaks: ChipPeaks | None = None,
 ) -> float:
-    """Roofline latency estimate (microseconds) for one tiling.
+    """Roofline latency estimate (microseconds) for one tiling, on the chip
+    `peaks` describes (default: `chip_peaks()`, the attached TPU's).
 
     HBM traffic counts tile re-streaming exactly as the BlockSpec index maps
     imply: the activation tile ignores the M grid axis (re-fetched per
@@ -171,6 +205,8 @@ def predict_us(
     tile is read once (its index map ignores the M axis), the codebook is
     resident for the whole grid, and codes never round-trip through HBM.
     """
+    peaks = peaks or chip_peaks()
+    mxu_f32, mxu_i8, hbm_bw = peaks.bf16_flops, peaks.int8_ops, peaks.hbm_bw
     gn, gm = _ceil_div(n, bn), (1 if kind == "encode" else _ceil_div(m, bm))
     gc = _ceil_div(c, bc)
 
@@ -182,11 +218,11 @@ def predict_us(
             + n * m * 4                      # output written exactly once
         )
         t_comp = (
-            2.0 * n * c * v * k / MXU_F32    # encode: once, not per M block
-            + 2.0 * n * c * k * m / MXU_I8   # int8 table contraction
+            2.0 * n * c * v * k / mxu_f32    # encode: once, not per M block
+            + 2.0 * n * c * k * m / mxu_i8   # int8 table contraction
         )
         t_steps = gn * gm * STEP_OVERHEAD_S
-        return (max(hbm / HBM_BW, t_comp) + t_steps) * 1e6
+        return (max(hbm / hbm_bw, t_comp) + t_steps) * 1e6
 
     x_bytes = n * c * v * 4 * gm
     p_bytes = c * k * v * 4 * gn * gm
@@ -195,21 +231,21 @@ def predict_us(
     t_serial = 0.0
     if kind == "encode":
         hbm = x_bytes + p_bytes + n * c * 4
-        t_comp = enc_flops / MXU_F32
+        t_comp = enc_flops / mxu_f32
     else:
         t_bytes = c * k * m * gn             # int8 table, re-read per N sweep
         o_bytes = n * m * 4                  # written exactly once (v2)
         hbm = x_bytes + p_bytes + t_bytes + o_bytes
         lut_flops = 2.0 * n * c * k * m
         if version >= 2:
-            t_comp = enc_flops / MXU_F32 + lut_flops / MXU_I8
+            t_comp = enc_flops / mxu_f32 + lut_flops / mxu_i8
         else:
             # v1: int8 -> fp32 dequant materialization per codebook step
             # (read int8 + write fp32 in VMEM), then an fp32 contraction.
-            t_comp = enc_flops / MXU_F32 + lut_flops / MXU_F32
-            t_serial = 5.0 * c * k * m * gn / VMEM_BW
+            t_comp = enc_flops / mxu_f32 + lut_flops / mxu_f32
+            t_serial = 5.0 * c * k * m * gn / (VMEM_BW_PER_HBM_BW * hbm_bw)
 
-    t_mem = hbm / HBM_BW
+    t_mem = hbm / hbm_bw
     t_steps = gn * gm * gc * STEP_OVERHEAD_S
     return (max(t_mem, t_comp) + t_serial + t_steps) * 1e6
 
@@ -235,7 +271,7 @@ def enumerate_candidates(
         bms = [0]
     else:
         bms = sorted({min(b, m) for b in _BM_CHOICES})
-    bcs = [c] if kind == "fused" else _divisors(c)
+    bcs = [c] if kind == "fused" else [d for d in _divisors(c) if _legal_bc(d, c, kind)]
     emitted = False
     for bn in bns:
         for bm in bms:
@@ -245,7 +281,7 @@ def enumerate_candidates(
                 emitted = True
                 yield BlockConfig(bn, bm, bc)
     if not emitted and kind != "fused":       # degenerate: smallest tiling
-        yield BlockConfig(min(8, n), 0 if kind == "encode" else min(128, m), 1)
+        yield BlockConfig(min(8, n), 0 if kind == "encode" else min(128, m), bcs[0])
 
 
 def heuristic(kind: str, n: int, m: int, c: int, k: int, v: int) -> BlockConfig:
@@ -262,9 +298,11 @@ def heuristic(kind: str, n: int, m: int, c: int, k: int, v: int) -> BlockConfig:
         return BlockConfig(bn, bm, c)
     bn = min(512 if kind == "encode" else 256, n)
     bm = 0 if kind == "encode" else min(512, m)
-    bc = max(1, min(c, 2048 // max(v, 1)))
-    while c % bc:
-        bc -= 1
+    cap = max(1, min(c, 2048 // max(v, 1)))
+    bc = max((d for d in _divisors(c) if d <= cap and _legal_bc(d, c, kind)),
+             default=c)
+    while bn > 8 and vmem_bytes(bn, max(bm, 1), bc, k, v, kind=kind) > VMEM_BUDGET:
+        bn //= 2
     return BlockConfig(bn, bm, bc)
 
 
@@ -418,20 +456,38 @@ def _measure_accepts_version(measure: Callable) -> bool:
     return len(positional) >= 2
 
 
+def _rank(
+    kind: str, n: int, m: int, c: int, k: int, v: int,
+    versions: tuple[int, ...], score: Callable[[BlockConfig, int], float],
+) -> tuple[BlockConfig | None, float, int]:
+    """(tiling, score in us, version) minimizing `score` over every legal
+    tiling of every version; (None, inf, versions[0]) when none scores
+    finite."""
+    best_cfg, best_t, best_ver = None, math.inf, versions[0]
+    for ver in versions:
+        cand_kind = "fused" if (kind == "lut_amm" and ver >= VERSION_FUSED) else kind
+        for cand in enumerate_candidates(cand_kind, n, m, c, k, v):
+            t_us = score(cand, ver)
+            if t_us < best_t:
+                best_cfg, best_t, best_ver = cand, t_us, ver
+    return best_cfg, best_t, best_ver
+
+
+def _analytic(kind, n, m, c, k, v) -> Callable[[BlockConfig, int], float]:
+    peaks = chip_peaks()
+    return lambda cand, ver: predict_us(
+        kind, n, m, c, k, v, cand.block_n, cand.block_m, cand.block_c,
+        version=ver, peaks=peaks)
+
+
 def best_analytic(
     kind: str, n: int, m: int, c: int, k: int, v: int, *, version: int = 2,
 ) -> tuple[BlockConfig | None, float]:
     """Best roofline-scored tiling for ONE kernel version; (None, inf) when
     no legal tiling exists (fused over VMEM budget). Used by the benchmarks
     for per-version model projections without touching the cache."""
-    cand_kind = "fused" if (kind == "lut_amm" and version >= VERSION_FUSED) else kind
-    best_cfg, best_t = None, math.inf
-    for cand in enumerate_candidates(cand_kind, n, m, c, k, v):
-        t_us = predict_us(kind, n, m, c, k, v,
-                          cand.block_n, cand.block_m, cand.block_c,
-                          version=version)
-        if t_us < best_t:
-            best_cfg, best_t = cand, t_us
+    best_cfg, best_t, _ = _rank(kind, n, m, c, k, v, (version,),
+                                _analytic(kind, n, m, c, k, v))
     return best_cfg, best_t
 
 
@@ -448,8 +504,10 @@ def tune(
     measure: optional wall-clock callable — `(cfg, version) -> seconds`
     (or legacy `(cfg) -> seconds`); when absent the analytic `predict_us`
     model scores candidates (the only option without an accelerator).
-    Candidates that raise or return inf never win, so illegal tilings on
-    the live backend are skipped rather than fatal.
+    A measure returns inf for a tiling the compiler refuses, which then
+    never wins; any other failure propagates. When every candidate is
+    refused on a TPU the tuner raises; off the chip (interpret mode) it
+    keeps the analytic ranking, flagged `measured: false`.
 
     versions: kernel generations to sweep; defaults to KERNEL_VERSIONS for
     kind="lut_amm" (v1/v2/fused is a tunable axis — DESIGN.md §13.2) and a
@@ -461,40 +519,23 @@ def tune(
     if versions is None:
         versions = KERNEL_VERSIONS if kind == "lut_amm" else (2,)
     measured = measure is not None
-    pass_version = measured and _measure_accepts_version(measure)
+    if measured:
+        pass_version = _measure_accepts_version(measure)
+        best_cfg, best_t, best_ver = _rank(
+            kind, n, m, c, k, v, versions,
+            lambda cand, ver: (measure(cand, ver) if pass_version
+                               else measure(cand)) * 1e6)
+        if not math.isfinite(best_t):
+            if backend == "tpu":
+                raise RuntimeError(
+                    f"autotune: the TPU refused every candidate tiling of {key}")
+            measured = False
+    if not measured:
+        best_cfg, best_t, best_ver = _rank(kind, n, m, c, k, v, versions,
+                                           _analytic(kind, n, m, c, k, v))
 
-    best_cfg, best_t, best_ver = None, math.inf, versions[0]
-    for ver in versions:
-        cand_kind = "fused" if (kind == "lut_amm" and ver >= VERSION_FUSED) else kind
-        for cand in enumerate_candidates(cand_kind, n, m, c, k, v):
-            if measure is not None:
-                try:
-                    t_us = (measure(cand, ver) if pass_version
-                            else measure(cand)) * 1e6
-                except Exception:
-                    continue
-            else:
-                t_us = predict_us(kind, n, m, c, k, v,
-                                  cand.block_n, cand.block_m, cand.block_c,
-                                  version=ver)
-            if t_us < best_t:
-                best_cfg, best_t, best_ver = cand, t_us, ver
-
-    if best_cfg is None or not math.isfinite(best_t):
-        # every measured candidate failed (e.g. backend can't run the
-        # kernels at all) — fall back to the analytic ranking rather than
-        # persisting nothing
-        measured = False
-        for ver in versions:
-            cand_kind = "fused" if (kind == "lut_amm" and ver >= VERSION_FUSED) else kind
-            for cand in enumerate_candidates(cand_kind, n, m, c, k, v):
-                t_us = predict_us(kind, n, m, c, k, v,
-                                  cand.block_n, cand.block_m, cand.block_c,
-                                  version=ver)
-                if t_us < best_t:
-                    best_cfg, best_t, best_ver = cand, t_us, ver
-
-    assert best_cfg is not None, f"no legal tiling for {key}"
+    if best_cfg is None:
+        raise RuntimeError(f"autotune: no legal tiling for {key}")
     record = {
         **best_cfg.as_dict(),
         "predicted_us": best_t,

@@ -24,11 +24,12 @@ def _encode_kernel(x_ref, p_ref, o_ref):
     a = x_ref[...].astype(jnp.float32)          # (bn, bc, V)
     p = p_ref[...].astype(jnp.float32)          # (bc, K, V)
     cross = jax.lax.dot_general(
-        a, p, (((2,), (2,)), ((1,), (0,))), preferred_element_type=jnp.float32
+        a, p, (((2,), (2,)), ((1,), (0,))),
+        precision=jax.lax.Precision.HIGHEST,    # fp32 codes, as core.pq
+        preferred_element_type=jnp.float32,
     )                                           # (bc, bn, K)
-    a_nrm = jnp.sum(a * a, axis=-1).T[:, :, None]
     p_nrm = jnp.sum(p * p, axis=-1)[:, None, :]
-    dists = a_nrm - 2.0 * cross + p_nrm
+    dists = cross * -2.0 + p_nrm       # distance less the per-row ||a||^2
     o_ref[...] = jnp.argmin(dists, axis=-1).astype(jnp.int32).T   # (bn, bc)
 
 

@@ -73,11 +73,14 @@ def _fused_decode_kernel(
         cross = jax.lax.dot_general(
             a, p,
             dimension_numbers=(((2,), (2,)), ((1,), (0,))),
+            precision=jax.lax.Precision.HIGHEST,         # fp32 codes, as core.pq
             preferred_element_type=jnp.float32,
         )                                                # (C, bn, K)
-        a_nrm = jnp.sum(a * a, axis=-1).T[:, :, None]    # (C, bn, 1)
+        # scores = squared distance less the per-row ||a||^2 (core.pq)
         p_nrm = jnp.sum(p * p, axis=-1)[:, None, :]      # (C, 1, K)
-        dists = a_nrm - 2.0 * cross + p_nrm              # (C, bn, K)
+        # this operand order: `p_nrm - 2.0 * cross` takes ~5x the VMEM
+        # temporaries on the v5e compiler
+        dists = cross * -2.0 + p_nrm                     # (C, bn, K)
         idx = jnp.argmin(dists, axis=-1)                 # (C, bn)
         if shared_scale:
             # (bn, C, K) layout: reshapes to the (bn, C·K) single-matmul form
@@ -97,6 +100,9 @@ def _fused_decode_kernel(
         acc32 = jax.lax.dot_general(
             codes.reshape(bn_, c_ * k_), t.reshape(c_ * k_, t.shape[-1]),
             dimension_numbers=(((1,), (0,)), ((), ())),
+            # exact integer MXU product; pinned so a global
+            # jax_default_matmul_precision cannot ask for fp32
+            precision=jax.lax.Precision.DEFAULT,
             preferred_element_type=jnp.int32,
         )                                                # (bn, bm) exact
         acc = acc32.astype(jnp.float32) * s_ref[...].reshape(1, -1)
@@ -109,6 +115,9 @@ def _fused_decode_kernel(
             part = jax.lax.dot_general(
                 codes[c0:c1], t[c0:c1],
                 dimension_numbers=(((2,), (1,)), ((0,), (0,))),
+                # exact integer MXU product; pinned so a global
+                # jax_default_matmul_precision cannot ask for fp32
+                precision=jax.lax.Precision.DEFAULT,
                 preferred_element_type=jnp.int32,
             )                                            # (cc, bn, bm)
             acc = acc + jnp.sum(part.astype(jnp.float32) * s[c0:c1], axis=0)

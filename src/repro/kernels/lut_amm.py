@@ -70,11 +70,14 @@ def _encode_onehot_i8(x_ref, p_ref) -> jax.Array:
     cross = jax.lax.dot_general(
         a, p,
         dimension_numbers=(((2,), (2,)), ((1,), (0,))),
+        precision=jax.lax.Precision.HIGHEST,     # fp32 codes, as core.pq
         preferred_element_type=jnp.float32,
     )
-    a_nrm = jnp.sum(a * a, axis=-1).T[:, :, None]        # (bc, bn, 1)
+    # scores = squared distance less the per-row ||a||^2 (core.pq)
     p_nrm = jnp.sum(p * p, axis=-1)[:, None, :]          # (bc, 1, K)
-    dists = a_nrm - 2.0 * cross + p_nrm                  # (bc, bn, K)
+    # this operand order: `p_nrm - 2.0 * cross` takes ~5x the VMEM
+    # temporaries on the v5e compiler
+    dists = cross * -2.0 + p_nrm                         # (bc, bn, K)
     # vectorized argmin over the K lane axis, then one-hot re-expansion —
     # int8 so the table read below runs on the int8 MXU path.
     idx = jnp.argmin(dists, axis=-1)                     # (bc, bn)
@@ -108,6 +111,9 @@ def _lut_amm_kernel_v2(
     part = jax.lax.dot_general(
         onehot, t_ref[...],
         dimension_numbers=(((2,), (1,)), ((0,), (0,))),
+        # exact integer MXU product; pinned so a global
+        # jax_default_matmul_precision cannot ask for fp32
+        precision=jax.lax.Precision.DEFAULT,
         preferred_element_type=jnp.int32,
     )
 

@@ -3,10 +3,10 @@
 `measure_lut_amm` builds the operands for one lut_amm shape ONCE and returns
 a `measure(cfg, version) -> seconds` callable that `autotune.tune` sweeps:
 each candidate (tiling × kernel version) is compiled and run on the live
-backend — one (or more) discarded warmup executions to absorb compile time,
-then the median of k timed runs. Candidates that fail to compile or execute
-(illegal tiling on the real hardware) return +inf so the sweep skips them
-instead of dying.
+backend — compiled ahead of the clock, one (or more) discarded warmup
+executions, then the median of k timed runs. A candidate the compiler
+refuses (a tiling the real hardware cannot hold) returns +inf so the sweep
+skips it; a failure while running propagates.
 
 This is what turns the autotuner's ranking from a roofline *projection* into
 a measurement: `ServingEngine` warmup uses it when REPRO_AUTOTUNE_MEASURE=1,
@@ -64,9 +64,9 @@ def measure_lut_amm(
     """
     from repro.kernels.fused_decode import fused_decode_pallas
     from repro.kernels.lut_amm import lut_amm_pallas, lut_amm_pallas_v1
+    from repro.kernels.ops import resolve_interpret
 
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     warmup = warmup if warmup is not None else _env_int("REPRO_AUTOTUNE_MEASURE_WARMUP", 1)
     reps = reps if reps is not None else _env_int("REPRO_AUTOTUNE_MEASURE_REPS", 5)
 
@@ -79,28 +79,35 @@ def measure_lut_amm(
 
     def measure(cfg: autotune.BlockConfig, version: int = 2) -> float:
         bn, bm, bc = cfg.block_n, cfg.block_m, cfg.block_c
+        args = (x, P, tq, scale)
         if version >= autotune.VERSION_FUSED:
             if bc != c:           # fused keeps all of C resident by definition
                 return math.inf
-            fn = lambda: fused_decode_pallas(
-                x, P, tq, scale, block_n=bn, block_m=bm, interpret=interpret)
+
+            def kernel(x, P, tq, scale):
+                return fused_decode_pallas(x, P, tq, scale, block_n=bn,
+                                           block_m=bm, interpret=interpret)
         elif version == 2:
-            fn = lambda: lut_amm_pallas(
-                x, P, tq, scale,
-                block_n=bn, block_m=bm, block_c=bc, interpret=interpret)
+            def kernel(x, P, tq, scale):
+                return lut_amm_pallas(x, P, tq, scale, block_n=bn, block_m=bm,
+                                      block_c=bc, interpret=interpret)
         else:
-            fn = lambda: lut_amm_pallas_v1(
-                x, P, tq, scale_v1,
-                block_n=bn, block_m=bm, block_c=bc, interpret=interpret)
+            args = (x, P, tq, scale_v1)
+
+            def kernel(x, P, tq, scale):
+                return lut_amm_pallas_v1(x, P, tq, scale, block_n=bn,
+                                         block_m=bm, block_c=bc,
+                                         interpret=interpret)
         try:
-            for _ in range(max(1, warmup)):
-                jax.block_until_ready(fn())              # compile off the clock
-            times = []
-            for _ in range(max(1, reps)):
-                t0 = time.perf_counter()
-                jax.block_until_ready(fn())
-                times.append(time.perf_counter() - t0)
-            return statistics.median(times)
-        except Exception:
-            return math.inf                              # illegal tiling: skip
+            run = jax.jit(kernel).lower(*args).compile()
+        except Exception:     # noqa: BLE001 — the compiler refused the tiling
+            return math.inf
+        for _ in range(max(1, warmup)):
+            jax.block_until_ready(run(*args))
+        times = []
+        for _ in range(max(1, reps)):
+            t0 = time.perf_counter()
+            jax.block_until_ready(run(*args))
+            times.append(time.perf_counter() - t0)
+        return statistics.median(times)
     return measure

@@ -1,10 +1,10 @@
 """jit'd public wrappers for the LUT kernels with platform + version dispatch.
 
-`lut_amm` runs the fused Pallas kernels on TPU and transparently falls back
-to interpret mode elsewhere (this container is CPU-only: interpret=True
-executes the kernel body in Python for correctness validation; the XLA
-one-hot path in repro.core.pq is the production fallback used by the
-distributed dry-run).
+`lut_amm` runs the compiled Pallas kernels on a TPU and interpret mode
+elsewhere (interpret=True executes the kernel body as XLA ops for
+correctness tests on the CPU); interpret mode on a TPU is refused
+(`resolve_interpret`). The XLA one-hot path in repro.core.pq is the
+separate `lut_use_kernel=False` path the distributed dry-run lowers.
 
 Kernel-version selection per shape comes from the autotune record
 (`autotune.kernel_choice`, DESIGN.md §13.3) — measured wall-clock winners
@@ -28,8 +28,17 @@ from repro.kernels.lut_amm import _apply_act, lut_amm_pallas, lut_amm_pallas_v1
 from repro.kernels.ref import encode_ref, lut_amm_ref
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def resolve_interpret(interpret: bool | None) -> bool:
+    """Interpret mode off the TPU, compiled kernels on it: `None` picks by
+    backend, and interpret mode on a TPU is refused — a served kernel there
+    must be the compiled one."""
+    on_tpu = jax.default_backend() == "tpu"
+    if interpret is None:
+        return not on_tpu
+    if interpret and on_tpu:
+        raise ValueError("interpret=True on a TPU backend: the LUT kernels "
+                         "run compiled there")
+    return interpret
 
 
 def lut_amm(
@@ -47,8 +56,7 @@ def lut_amm(
     interpret: bool | None = None,
 ) -> jax.Array:
     """LUT-NN approximate matmul, autotuned dispatch: (N, D) -> (N, M)."""
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = resolve_interpret(interpret)
     n, _ = x.shape
     c, k, v = centroids.shape
     m = table_q.shape[-1]
@@ -98,8 +106,7 @@ def lut_amm_v1(
     interpret: bool | None = None,
 ) -> jax.Array:
     """Original fused kernel (fp32 dequant per step + o_ref accumulation)."""
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = resolve_interpret(interpret)
     return lut_amm_pallas_v1(
         x,
         centroids,
@@ -121,8 +128,7 @@ def encode(
     interpret: bool | None = None,
 ) -> jax.Array:
     """Closest-centroid encode: (N, D) -> int32 (N, C)."""
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = resolve_interpret(interpret)
     return encode_pallas(
         x, centroids, block_n=block_n, block_c=block_c, interpret=interpret
     )
@@ -141,8 +147,7 @@ def lut_amm_fused(
     interpret: bool | None = None,
 ) -> jax.Array:
     """Fused encode→lookup decode kernel (v3), explicitly."""
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = resolve_interpret(interpret)
     return fused_decode_pallas(
         x, centroids, table_q, scale, bias=bias, act=act,
         block_n=block_n, block_m=block_m, interpret=interpret,

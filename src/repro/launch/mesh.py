@@ -3,10 +3,8 @@
 Functions (not module-level constants) so importing this module never
 touches jax device state — the dry-run must set XLA_FLAGS before first init.
 
-`make_mesh` here is the one mesh constructor the repo uses: it requests
-explicit `Auto` axis types on jax versions that support them and falls back
-cleanly on versions that predate `jax.sharding.AxisType` (where every axis
-is implicitly auto-sharded, i.e. the same semantics).
+`make_mesh` here is the one mesh constructor the repo uses: every axis is
+explicitly `Auto` (GSPMD-propagated) sharding.
 """
 
 from __future__ import annotations
@@ -16,34 +14,21 @@ from typing import Sequence
 import jax
 
 
-def _auto_axis_types_kw(n_axes: int) -> dict:
-    """{'axis_types': (Auto,)*n} on jax versions that have AxisType, else {}."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+def _auto(n_axes: int) -> tuple:
+    return (jax.sharding.AxisType.Auto,) * n_axes
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]) -> jax.sharding.Mesh:
-    """Version-portable `jax.make_mesh` with Auto axis types when available."""
+    """`jax.make_mesh` with Auto axis types."""
     axes = tuple(axes)
-    kw = _auto_axis_types_kw(len(axes))
-    if kw:
-        try:
-            return jax.make_mesh(tuple(shape), axes, **kw)
-        except TypeError:
-            pass                     # make_mesh predates the kwarg
-    return jax.make_mesh(tuple(shape), axes)
+    return jax.make_mesh(tuple(shape), axes, axis_types=_auto(len(axes)))
 
 
 def mesh_from_devices(devices, axes: Sequence[str]) -> jax.sharding.Mesh:
-    """Version-portable `jax.sharding.Mesh` over an explicit device array
-    (the elastic-rescale path, where the surviving devices are hand-picked)."""
+    """`jax.sharding.Mesh` over an explicit device array (the elastic-rescale
+    path, where the surviving devices are hand-picked)."""
     axes = tuple(axes)
-    try:
-        return jax.sharding.Mesh(devices, axes, **_auto_axis_types_kw(len(axes)))
-    except TypeError:
-        return jax.sharding.Mesh(devices, axes)
+    return jax.sharding.Mesh(devices, axes, axis_types=_auto(len(axes)))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

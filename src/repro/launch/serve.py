@@ -16,7 +16,8 @@ over the continuous-batching engine with a LUT_INFER (int8 table) model.
   XLA_FLAGS=--xla_force_host_platform_device_count=2 \\
   PYTHONPATH=src python -m repro.launch.serve --artifact <dir> --tp 2 --dtype bfloat16
 
-  # no artifact: randomly-initialized tables (smoke/perf mode only)
+  # no artifact: randomly-initialized tables at the published width
+  # (smoke/perf mode only; --layers/--d-model/--vocab cut it down):
   PYTHONPATH=src python -m repro.launch.serve --arch qwen3_1p7b --requests 8
 
 In batch mode a warm-up request runs (and is discarded) before the timed
@@ -29,6 +30,7 @@ requests unresolved.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import sys
 import time
 
@@ -37,6 +39,7 @@ import jax.numpy as jnp
 
 from repro.configs import ARCH_IDS, build_model, get_arch, reduce_arch
 from repro.core.amm import Mode
+from repro.launch.compile_cache import init_compile_cache
 from repro.serving.engine import KV_DTYPES, ServingEngine
 from repro.serving.sampling import SamplingParams
 
@@ -102,7 +105,8 @@ def main(argv: list[str] | None = None) -> None:
                     help="run LUT sites through the fused Pallas v2 kernel "
                          "(random-init mode; artifacts carry their own "
                          "lut_use_kernel setting)")
-    # random-init reductions (CI / laptop smoke: serve a tiny model)
+    # random-init reductions (CI / laptop smoke: serve a tiny model); with
+    # none of them, random-init mode serves the published architecture
     ap.add_argument("--layers", type=int, default=None,
                     help="random-init mode: reduce the arch to N layers")
     ap.add_argument("--d-model", type=int, default=None,
@@ -172,6 +176,7 @@ def main(argv: list[str] | None = None) -> None:
         ap.error("--spec-decode does not compose with --tp > 1 (the draft "
                  "caches are host-managed)")
 
+    init_compile_cache()
     if args.port is not None:
         return _serve_http(args)
 
@@ -187,9 +192,11 @@ def main(argv: list[str] | None = None) -> None:
         )
         source = f"artifact {args.artifact} ({art.arch_name})"
     else:
-        arch = _reduced_arch(args)
+        arch = _random_init_arch(args)
         bundle = build_model(arch, Mode.LUT_INFER)
-        params = bundle.init(jax.random.PRNGKey(0))
+        # one compiled init: op by op, a published width compiles hundreds
+        # of small programs
+        params = jax.jit(bundle.init)(jax.random.PRNGKey(0))
         use_kernel = args.use_kernel
         source = f"random init ({arch.name})"
 
@@ -297,15 +304,19 @@ def _resolve_draft(engine_kwargs: dict, artifact: str | None) -> dict:
     return engine_kwargs
 
 
-def _reduced_arch(args):
-    overrides = {"lut_use_kernel": args.use_kernel}
-    if args.layers is not None:
-        overrides["n_layers"] = args.layers
-    if args.d_model is not None:
-        overrides["d_model"] = args.d_model
-    if args.vocab is not None:
-        overrides["vocab"] = args.vocab
-    return reduce_arch(get_arch(args.arch), **overrides)
+def _random_init_arch(args):
+    """The published arch with `lut_use_kernel` from the flag; cut down by
+    `reduce_arch` only when --layers, --d-model or --vocab is given."""
+    cuts = {
+        field: value for field, value in (
+            ("n_layers", args.layers), ("d_model", args.d_model),
+            ("vocab", args.vocab),
+        ) if value is not None
+    }
+    arch = get_arch(args.arch)
+    if cuts:
+        arch = reduce_arch(arch, **cuts)
+    return dataclasses.replace(arch, lut_use_kernel=args.use_kernel)
 
 
 def _serve_http(args) -> None:
@@ -361,9 +372,9 @@ def _serve_http(args) -> None:
             bundle, params = art.bundle, art.params
             source = f"artifact {args.artifact} ({art.arch_name})"
         else:
-            arch = _reduced_arch(args)
+            arch = _random_init_arch(args)
             bundle = build_model(arch, Mode.LUT_INFER)
-            params = bundle.init(jax.random.PRNGKey(0))
+            params = jax.jit(bundle.init)(jax.random.PRNGKey(0))
             source = f"random init ({arch.name})"
         mesh = None
         if args.tp > 1:

@@ -30,6 +30,7 @@ import argparse
 
 from repro.configs import ARCH_IDS, get_arch, reduce_arch
 from repro.data import MarkovLM
+from repro.launch.compile_cache import init_compile_cache
 from repro.train.recipe import Recipe, default_recipe
 
 
@@ -100,6 +101,7 @@ def main(argv: list[str] | None = None) -> None:
         print(f"wrote recipe ({recipe.describe()}) to {args.dump_recipe}")
         return
 
+    init_compile_cache()
     arch = reduce_arch(
         get_arch(args.arch),
         d_model=args.d_model,

@@ -90,13 +90,13 @@ def expert_linear(s: ExpertSiteCfg, p: Params, x: jax.Array) -> jax.Array:
     P = p["centroids"]
     e, cap, _ = x.shape
     xf = x.reshape(e * cap, s.d_in)
-    dists = pq.pairwise_sq_dists(pq.split_subvectors(xf, s.lut.v), P)
+    scores = pq.encode_scores(pq.split_subvectors(xf, s.lut.v), P)
     if s.mode == Mode.LUT_TRAIN:
-        enc = pq.ste_encode(dists, temperature(p["log_t"]))
+        enc = pq.ste_encode(scores, temperature(p["log_t"]))
         tables = _expert_tables_train(p, s)
     elif s.lut.int8_dot:
         # integer batched contraction: tables stream once as int8
-        enc8 = pq.hard_encode(dists).reshape(e, cap, -1).astype(jnp.int8)
+        enc8 = pq.hard_encode(scores).reshape(e, cap, -1).astype(jnp.int8)
         tq = p["table_q"].reshape(e, -1, s.d_out)
         acc = jax.lax.dot_general(
             enc8, tq, (((2,), (1,)), ((0,), (0,))),
@@ -104,7 +104,7 @@ def expert_linear(s: ExpertSiteCfg, p: Params, x: jax.Array) -> jax.Array:
         )
         return (acc.astype(jnp.float32) * p["table_scale"].reshape(e, 1, s.d_out)).astype(x.dtype)
     else:
-        enc = pq.hard_encode(dists)
+        enc = pq.hard_encode(scores)
         tables = (p["table_q"].astype(jnp.float32) * p["table_scale"]).astype(x.dtype)
     enc = enc.reshape(e, cap, -1).astype(x.dtype)             # (E, Cap, C*K)
     tbl = tables.reshape(e, tables.shape[1] * tables.shape[2], s.d_out)

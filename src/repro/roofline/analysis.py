@@ -15,8 +15,9 @@ optimized per-device HLO with the standard ring cost model:
   all-to-all        (n-1)/n x input   ~ input bytes
   collective-permute  input bytes
 
-Hardware constants: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link
-ICI (we charge one link; multi-link overlap is an upside not claimed here).
+Hardware constants come from `chip_peaks`, one table keyed by the device
+kind JAX reports. Collectives are charged one ICI link (multi-link overlap
+is an upside not claimed here).
 """
 
 from __future__ import annotations
@@ -25,9 +26,43 @@ import dataclasses
 import re
 from typing import Any
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks."""
+
+    bf16_flops: float        # dense MXU FLOP/s at bf16
+    int8_ops: float          # MXU OP/s at int8
+    hbm_bw: float            # HBM bytes/s
+    ici_bw: float            # bytes/s of ONE inter-chip link
+
+
+# Keyed by `jax.Device.device_kind`. Source: Google Cloud documentation,
+# "TPU v5e" (cloud.google.com/tpu/docs/v5e): 197 TFLOP/s bf16, 393 TOP/s
+# int8, 16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI over 4 links (50 GB/s each).
+CHIP_PEAKS = {
+    "TPU v5 lite": ChipPeaks(bf16_flops=197e12, int8_ops=393e12,
+                             hbm_bw=819e9, ici_bw=50e9),
+}
+# the chip the analytic models describe when no TPU is attached
+MODEL_KIND = "TPU v5 lite"
+
+
+def chip_peaks(kind: str | None = None) -> ChipPeaks:
+    """Peaks of `kind`; by default of the attached TPU, or of `MODEL_KIND`
+    when the default device is not a TPU (CPU tests, interpret mode).
+
+    A TPU kind missing from `CHIP_PEAKS` is an error: a model of another
+    chip would mis-rank kernels and mis-state roofline shares there."""
+    if kind is None:
+        import jax
+
+        dev = jax.devices()[0]
+        kind = dev.device_kind if dev.platform == "tpu" else MODEL_KIND
+    if kind not in CHIP_PEAKS:
+        raise KeyError(f"no published peaks for device kind {kind!r}: add it "
+                       f"to CHIP_PEAKS (repro.roofline.analysis) with its source")
+    return CHIP_PEAKS[kind]
 
 _DTYPE_BYTES = {
     "f64": 8, "s64": 8, "u64": 8,
@@ -81,9 +116,19 @@ class Roofline:
     hbm_bytes: float              # per-device bytes accessed
     coll_bytes: float             # per-device wire bytes
     coll_by_kind: dict[str, float]
-    peak_flops: float = PEAK_FLOPS
-    hbm_bw: float = HBM_BW
-    ici_bw: float = ICI_BW
+    peaks: ChipPeaks = CHIP_PEAKS[MODEL_KIND]
+
+    @property
+    def peak_flops(self) -> float:
+        return self.peaks.bf16_flops
+
+    @property
+    def hbm_bw(self) -> float:
+        return self.peaks.hbm_bw
+
+    @property
+    def ici_bw(self) -> float:
+        return self.peaks.ici_bw
 
     @property
     def t_compute(self) -> float:
@@ -140,6 +185,7 @@ def analyze_compiled(compiled) -> Roofline:
         hbm_bytes=cost.bytes,
         coll_bytes=cost.coll_bytes,
         coll_by_kind=dict(cost.coll),
+        peaks=chip_peaks(),
     )
 
 

@@ -544,6 +544,30 @@ class ServingEngine:
         self.finished.clear()
         self.reset_stats()
 
+    def prefill_logits(self, prompt: list[int]) -> jax.Array:
+        """`(len(prompt), vocab)` logits of one prompt's prefill through the
+        engine's own jitted step, in slot 0 of fresh caches — the served
+        forward, for checking against a reference. Engine state (caches,
+        slots, counters) is untouched."""
+        if self.paged:
+            raise ValueError("prefill_logits runs on dense caches only")
+        if not 1 <= len(prompt) <= self.prefill_chunk:
+            raise ValueError(f"prompt length {len(prompt)} must be in "
+                             f"[1, prefill_chunk={self.prefill_chunk}]")
+        toks = np.zeros((self.n_slots, self.prefill_chunk), np.int32)
+        toks[0, : len(prompt)] = prompt
+        mask = np.zeros((self.n_slots,), bool)
+        mask[0] = True
+        caches = self.bundle.init_caches(self.n_slots, self.max_seq,
+                                         dtype=self.kv_dtype)
+        if self.rules is not None:
+            caches = jax.device_put(caches, self._cache_shardings)
+        logits, _ = self._step_fn(
+            self.params, jnp.asarray(toks),
+            jnp.zeros((self.n_slots,), jnp.int32), caches, jnp.asarray(mask),
+        )
+        return logits[0, : len(prompt)]
+
     def submit(
         self,
         prompt: list[int],

@@ -17,7 +17,7 @@ a dead worker process — is injectable on demand, deterministically:
                       it to `os._exit`).
 
 Determinism: probabilistic faults are drawn from `random.Random` seeded with
-`(spec.seed, call_index)`, where the call index is the injector's own
+`(spec.seed, call_index, channel)` (as one string), where the call index is the injector's own
 monotonic counter — NOT the engine step counter. A retried step therefore
 advances to the next draw, which is exactly what a transient fault should
 look like (fail once, succeed on retry), while the full draw sequence stays
@@ -103,8 +103,9 @@ class FaultInjector:
 
     def _draw(self, n: int, channel: str) -> float:
         # independent stream per (seed, call, channel): a spike draw never
-        # perturbs the error draw sequence
-        return random.Random((self.spec.seed, n, channel)).random()
+        # perturbs the error draw sequence. A str seed is hashed with
+        # SHA-512, so the stream does not depend on PYTHONHASHSEED.
+        return random.Random(f"{self.spec.seed}|{n}|{channel}").random()
 
     def on_step(self) -> None:
         """Engine hook, called at the top of every `ServingEngine.step()`.

@@ -184,7 +184,7 @@ class EngineRouter:
         self._replicas = [
             _Replica(i, EngineSupervisor(
                 artifact_path, engine_kwargs=engine_kwargs,
-                faults=fault_list[i], **sup_kwargs,
+                faults=fault_list[i], chip=i, **sup_kwargs,
             ))
             for i in range(replicas)
         ]

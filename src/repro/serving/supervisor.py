@@ -40,6 +40,7 @@ from __future__ import annotations
 import dataclasses
 import multiprocessing as mp
 import os
+import socket
 import threading
 import time
 from typing import Any, Callable
@@ -55,20 +56,44 @@ _STATS_PERIOD_S = 0.25
 # worker side
 # ---------------------------------------------------------------------------
 
+def _pin_chip(chip: int) -> None:
+    """Make TPU chip `chip` this process's only device: a one-chip slice of
+    its own, so replicas on one host each hold one chip and libtpu lets them
+    load side by side. Must run before JAX first touches a device; the
+    variables are inert off the TPU."""
+    with socket.socket() as s:          # a free port for this slice's runtime
+        s.bind(("localhost", 0))
+        port = s.getsockname()[1]
+    os.environ.update({
+        "TPU_VISIBLE_CHIPS": str(chip),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_ADDRESSES": f"localhost:{port}",
+        "TPU_PROCESS_PORT": str(port),
+        "CLOUD_TPU_TASK_ID": "0",
+    })
+
+
 def _worker_main(
     conn,
     artifact_path: str,
     engine_kwargs: dict[str, Any],
     fault_dict: dict[str, Any] | None,
     step_retries: int,
+    chip: int | None = None,
 ) -> None:
     """Worker entry point: load the artifact, build the engine, serve the
     pipe. Crashes are the supervisor's problem — this function either runs
-    forever or exits the process."""
+    forever or exits the process. `chip`: the one TPU chip this worker
+    owns (None: every device the process can see)."""
+    from repro.launch.compile_cache import init_compile_cache
     from repro.serving.artifact import load_artifact
     from repro.serving.engine import ServingEngine, TokenTap, submit_from_spec
     from repro.serving.faults import FaultInjector, FaultSpec, InjectedKill
 
+    if chip is not None:
+        _pin_chip(chip)
+    init_compile_cache()
     try:
         art = load_artifact(artifact_path)
         injector = (
@@ -175,8 +200,10 @@ class EngineSupervisor:
         step_retries: int = 1,
         healthy_after_s: float = 5.0,
         mp_context: str = "spawn",
+        chip: int | None = None,
     ):
         self.artifact_path = str(artifact_path)
+        self.chip = chip
         self.engine_kwargs = dict(engine_kwargs or {})
         self.faults = faults
         self.faults_once = faults_once
@@ -431,7 +458,7 @@ class EngineSupervisor:
             proc = self._ctx.Process(
                 target=_worker_main,
                 args=(child_conn, self.artifact_path, self.engine_kwargs,
-                      fault_dict, self.step_retries),
+                      fault_dict, self.step_retries, self.chip),
                 daemon=True,
             )
             proc.start()
@@ -474,6 +501,11 @@ class EngineSupervisor:
             consecutive += 1
             self.counters["restarts"] += 1
             parent_conn.close()
+            # its chip is free for the next worker only once it has exited
+            proc.join(timeout=10)
+            if proc.is_alive():
+                proc.kill()
+                proc.join()
             if consecutive > self.max_restarts:
                 self._fail_closed(
                     self._last_crash

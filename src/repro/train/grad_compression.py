@@ -105,12 +105,7 @@ def make_compressed_grad_fn(
 
     batch_spec = P(axis)
     rep = P()
-    specs = dict(in_specs=(rep, rep, batch_spec), out_specs=(rep, rep, rep))
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(local, mesh=mesh, check_vma=False, **specs)
-    # jax < 0.6: experimental home, and the no-replication-check kwarg is
-    # spelled check_rep rather than check_vma
-    from jax.experimental.shard_map import shard_map as sm
-
-    return sm(local, mesh=mesh, check_rep=False, **specs)
+    return jax.shard_map(
+        local, mesh=mesh, check_vma=False,
+        in_specs=(rep, rep, batch_spec), out_specs=(rep, rep, rep),
+    )

@@ -64,7 +64,9 @@ def test_tune_cache_roundtrip(tmp_path):
 def test_tune_picks_measured_winner(tmp_path):
     """With a measure callable the tuner minimizes wall-clock, not the model."""
     cache = autotune.AutotuneCache(tmp_path / "m.json")
-    target = autotune.BlockConfig(16, 128, 2)
+    # bc = C = 4: a block of the (bn, bc, V) activations is a multiple of
+    # the 8-row sublane tile or the whole codebook axis
+    target = autotune.BlockConfig(16, 128, 4)
 
     def measure(cfg):
         return 1e-6 if cfg == target else 1e-3
@@ -76,10 +78,11 @@ def test_tune_picks_measured_winner(tmp_path):
 
 def test_tune_sweeps_version_axis(tmp_path):
     """Kernel version is a tunable axis (DESIGN.md §13.2): the analytic
-    sweep on a big decode shape picks the fused kernel (encode charged once,
-    not per M block) and records it."""
+    sweep on a decode shape (8 slots) picks the fused kernel (encode charged
+    once, not per M block) and records it. At prefill widths the all-of-C
+    working set caps fused at small N tiles and v2 wins instead."""
     cache = autotune.AutotuneCache(tmp_path / "v.json")
-    best, rec = autotune.tune("lut_amm", 256, 4096, 128, 16, 32, cache=cache)
+    best, rec = autotune.tune("lut_amm", 8, 4096, 128, 16, 32, cache=cache)
     assert rec["version"] == autotune.VERSION_FUSED
     assert best.block_c == 128          # fused keeps the whole codebook axis
     assert not rec["measured"]
@@ -98,6 +101,16 @@ def test_tune_measured_version_wins_over_analytic_ranking(tmp_path):
                               cache=cache, measure=measure)
     assert rec["version"] == 1 and rec["measured"]
     assert rec["source"] == "wallclock"
+
+
+def test_tune_all_refused_on_tpu_raises(tmp_path):
+    """On a TPU a measured sweep in which the compiler refuses every
+    candidate is a failure, not a silent fall back to the model."""
+    cache = autotune.AutotuneCache(tmp_path / "tpu.json")
+    with pytest.raises(RuntimeError, match="refused every candidate"):
+        autotune.tune("lut_amm", 64, 256, 4, 16, 8, backend="tpu", cache=cache,
+                      measure=lambda cfg, ver: float("inf"))
+    assert cache.load() == {}
 
 
 def test_tune_all_inf_measure_falls_back_to_analytic(tmp_path):
@@ -148,9 +161,9 @@ def test_kernel_choice_fallback_rules(tmp_path):
 def test_best_analytic_per_version():
     """best_analytic scores ONE version at its own legal tilings; fused
     reports (None, inf) when no all-of-C tiling fits VMEM."""
-    cfg2, t2 = autotune.best_analytic("lut_amm", 256, 4096, 128, 16, 32,
+    cfg2, t2 = autotune.best_analytic("lut_amm", 8, 4096, 128, 16, 32,
                                       version=2)
-    cfg3, t3 = autotune.best_analytic("lut_amm", 256, 4096, 128, 16, 32,
+    cfg3, t3 = autotune.best_analytic("lut_amm", 8, 4096, 128, 16, 32,
                                       version=3)
     assert cfg2 is not None and cfg3 is not None and t3 < t2
     cfg_bad, t_bad = autotune.best_analytic("lut_amm", 8, 4096, 4096, 16, 64,

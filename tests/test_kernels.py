@@ -183,3 +183,16 @@ def test_kernel_argmin_tie_break(key):
     x = jnp.zeros((8, 4))
     out = encode_pallas(x, P, interpret=True)
     assert int(jnp.max(out)) == 0
+
+
+def test_resolve_interpret_refuses_interpret_mode_on_tpu(monkeypatch):
+    """Off the TPU `None` means interpret mode; on a TPU the kernels run
+    compiled, and an explicit interpret=True is refused."""
+    from repro.kernels import ops
+
+    assert ops.resolve_interpret(None) is True          # CPU test process
+    assert ops.resolve_interpret(False) is False
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "tpu")
+    assert ops.resolve_interpret(None) is False
+    with pytest.raises(ValueError, match="compiled"):
+        ops.resolve_interpret(True)

@@ -2,6 +2,7 @@
 
 import jax
 import jax.numpy as jnp
+import pytest
 
 from repro.roofline.analysis import Roofline, collective_bytes, _shape_bytes
 
@@ -70,3 +71,15 @@ def test_real_compiled_module_collectives():
         n_devices=4,
     )
     assert "COLL_BYTES" in out
+
+
+def test_chip_peaks_table_by_device_kind():
+    """Peaks are looked up by the device kind JAX reports; off the TPU the
+    analytic models describe MODEL_KIND; an unknown TPU kind is an error."""
+    from repro.roofline.analysis import CHIP_PEAKS, MODEL_KIND, chip_peaks
+
+    v5e = chip_peaks("TPU v5 lite")
+    assert (v5e.bf16_flops, v5e.hbm_bw) == (197e12, 819e9)
+    assert chip_peaks() is CHIP_PEAKS[MODEL_KIND]       # CPU test process
+    with pytest.raises(KeyError, match="TPU v99"):
+        chip_peaks("TPU v99")

@@ -204,3 +204,27 @@ def test_stats_counters(key):
     assert st["prefill_s"] > 0 and st["decode_s"] > 0
     # 1 prefill + 3 decode forwards over 2 shapes: 2 misses, 2 hits
     assert st["shape_cache_hits"] == st["prefill_forwards"] + st["decode_forwards"] - 2
+
+
+@pytest.mark.parametrize("use_kernel", [False, True], ids=["xla", "kernel"])
+def test_prefill_logits_match_train_logits(key, use_kernel):
+    """The engine's served prefill forward agrees with the uncached
+    training forward on the same LUT_INFER params, for the XLA one-hot
+    path and the (interpret-mode) kernel path; engine state is untouched."""
+    arch = reduce_arch(get_arch("qwen3_1p7b"), n_layers=2,
+                       lut_use_kernel=use_kernel)
+    bundle = build_model(arch, Mode.LUT_INFER)
+    params = bundle.init(key)
+    ref_bundle = build_model(reduce_arch(get_arch("qwen3_1p7b"), n_layers=2),
+                             Mode.LUT_INFER)
+    prompt = [5, 17, 3, 99, 42, 7]
+    toks = jnp.asarray([prompt], jnp.int32)
+    ref, _ = ref_bundle.train_logits(params, {"tokens": toks, "labels": toks},
+                                     compute_dtype=jnp.float32)
+    eng = ServingEngine(bundle, params, n_slots=2, max_seq=32, prefill_chunk=8)
+    got = eng.prefill_logits(prompt)
+    assert got.shape == (len(prompt), arch.vocab)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref[0]), atol=1e-4)
+    assert not eng.has_work() and eng.stats()["prefill_forwards"] == 0
+    with pytest.raises(ValueError):
+        eng.prefill_logits(list(range(1, 10)))          # longer than one chunk

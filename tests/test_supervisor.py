@@ -161,3 +161,20 @@ def test_check_artifact_dir_probe(artifact, tmp_path):
     (bad / "manifest.json").write_text("{not json")
     with pytest.raises(ValueError, match="manifest"):
         check_artifact_dir(bad)
+
+
+def test_pin_chip_gives_worker_one_chip_slice(monkeypatch):
+    """A router replica's worker owns exactly one chip: a one-chip slice of
+    its own, with its own runtime port, set before JAX starts."""
+    from repro.serving import supervisor
+
+    keys = ("TPU_VISIBLE_CHIPS", "TPU_CHIPS_PER_PROCESS_BOUNDS",
+            "TPU_PROCESS_BOUNDS", "TPU_PROCESS_ADDRESSES", "TPU_PROCESS_PORT",
+            "CLOUD_TPU_TASK_ID")
+    for k in keys:                        # restored by monkeypatch afterwards
+        monkeypatch.setenv(k, "unset")
+    supervisor._pin_chip(2)
+    env = {k: supervisor.os.environ[k] for k in keys}
+    assert env["TPU_VISIBLE_CHIPS"] == "2"
+    assert env["TPU_CHIPS_PER_PROCESS_BOUNDS"] == env["TPU_PROCESS_BOUNDS"] == "1,1,1"
+    assert env["TPU_PROCESS_ADDRESSES"] == f"localhost:{env['TPU_PROCESS_PORT']}"

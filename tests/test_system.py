@@ -70,3 +70,48 @@ def test_int8_table_accuracy_claim(key):
     e_fp = float(jnp.linalg.norm(y_fp32 - y_ref))
     e_i8 = float(jnp.linalg.norm(y_int8 - y_ref))
     assert e_i8 < 1.05 * e_fp + 1e-3               # int8 adds <5% extra error
+
+
+def test_compile_cache_placement(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set: it stands and nothing else is set.
+    Unset: the cache goes to the fixed, git-ignored <checkout>/.jax_cache."""
+    import pathlib
+
+    from repro.launch import compile_cache
+
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        jax.config.update("jax_compilation_cache_dir", None)
+        assert compile_cache.init_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir is None
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = compile_cache.init_compile_cache()
+        root = pathlib.Path(__file__).resolve().parents[1]
+        assert path == str(root / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+        assert ".jax_cache/" in (root / ".gitignore").read_text().split()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+
+
+def test_serve_random_init_is_published_arch_unless_cut():
+    """`launch/serve.py --arch X` serves the published width; only
+    --layers/--d-model/--vocab cut it down."""
+    import argparse
+
+    from repro.configs import get_arch
+    from repro.launch.serve import _random_init_arch
+
+    def args(**kw):
+        base = dict(arch="qwen3_1p7b", use_kernel=True, layers=None,
+                    d_model=None, vocab=None)
+        return argparse.Namespace(**{**base, **kw})
+
+    published = get_arch("qwen3_1p7b")
+    arch = _random_init_arch(args())
+    assert (arch.n_layers, arch.d_model, arch.vocab, arch.lut_v) == (
+        published.n_layers, published.d_model, published.vocab, published.lut_v)
+    assert arch.lut_use_kernel
+    small = _random_init_arch(args(layers=2, d_model=64, vocab=128))
+    assert (small.n_layers, small.d_model, small.vocab) == (2, 64, 128)
