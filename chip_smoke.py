@@ -38,6 +38,7 @@ is `{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}`.
 from __future__ import annotations
 
 import argparse
+import collections
 import contextlib
 import json
 import os
@@ -190,6 +191,9 @@ def phase_engine(use_kernel: bool) -> dict:
 
     device = _tpu_devices(1)
     cache_dir = init_compile_cache()
+    cache_events = collections.Counter()        # persistent-cache reads/writes
+    jax.monitoring.register_event_listener(
+        lambda event, **_: cache_events.update([event.rsplit("/", 1)[-1]]))
     arch = _arch(use_kernel)
     bundle = build_model(arch, Mode.LUT_INFER)
     t0 = time.perf_counter()
@@ -199,9 +203,12 @@ def phase_engine(use_kernel: bool) -> dict:
     eng = ServingEngine(bundle, params, n_slots=SLOTS, max_seq=MAX_SEQ,
                         prefill_chunk=CHUNK)            # autotune warmup
     autotune_s = time.perf_counter() - t0
+    before = dict(cache_events)
     t0 = time.perf_counter()
     eng.warmup()                  # compiles the prefill and decode steps
     compile_s = time.perf_counter() - t0
+    warmup_cache = {k: cache_events[k] - before.get(k, 0)
+                    for k in ("cache_hits", "cache_misses")}
     served = _serve(eng)
     got = np.asarray(eng.prefill_logits(CHECK_PROMPT))
     peak = (jax.devices()[0].memory_stats() or {}).get("peak_bytes_in_use")
@@ -214,6 +221,7 @@ def phase_engine(use_kernel: bool) -> dict:
             "name", "n_layers", "d_model", "d_ff", "vocab", "lut_k", "lut_v")},
         "lut_sites": len(bundle.lut_sites()),
         "init_s": init_s, "autotune_s": autotune_s, "compile_s": compile_s,
+        "warmup_cache": warmup_cache,
         "reference_s": reference_s, "peak_bytes_in_use": peak,
         **served, "logits": check,
     }
@@ -407,9 +415,11 @@ def _print_engine(label: str, r: dict) -> None:
     print(f"{label}: {a['name']} n_layers={a['n_layers']} d_model={a['d_model']} "
           f"d_ff={a['d_ff']} vocab={a['vocab']} lut_k={a['lut_k']} "
           f"lut_v={a['lut_v']} ({r['lut_sites']} LUT sites) on {r['device']}")
+    c = r["warmup_cache"]
     print(f"  compile (warmup) {r['compile_s']:.2f}s, autotune {r['autotune_s']:.2f}s, "
           f"param init {r['init_s']:.2f}s, process {r['process_s']:.2f}s, "
-          f"cache {r['compile_cache']}")
+          f"cache {r['compile_cache']} (warmup read {c['cache_hits']}, "
+          f"wrote {c['cache_misses']} entries)")
     print(f"  served {r['generated']} tokens in {r['wall_s']:.3f}s = "
           f"{r['tok_s']:.2f} tok/s (prefill {r['prefill_tok_s']:.2f} tok/s, "
           f"decode {r['decode_tok_s']:.2f} tok/s), peak device memory "
@@ -443,7 +453,13 @@ def _one_chip() -> dict:
           f"{c['exit_code']} after SIGTERM")
     failures = {n: r["failures"] for n, r in
                 (("A", a), ("A-warm", warm), ("B", b), ("C", c))}
-    if warm["compile_s"] >= a["compile_s"]:
+    # the second process must find every step program the first one wrote
+    # (a cache that came warm with the machine leaves the first with none
+    # to write); when the first did compile, the second must be faster
+    wc = warm["warmup_cache"]
+    if wc["cache_misses"] or not wc["cache_hits"]:
+        failures["A-warm"].append(f"second process missed the cache: {wc}")
+    if a["warmup_cache"]["cache_misses"] and warm["compile_s"] >= a["compile_s"]:
         failures["A-warm"].append("warm process compiled no faster than cold")
     # A-warm and C run phase A's program on its parameters: same tokens.
     # B's kernels round differently, so its greedy tokens may part from A's
