@@ -1,0 +1,13 @@
+"""Share of the forwards' device time spent writing the KV cache: each
+layer's new keys and values, and the engine's select-merge of the
+whole cache (scope `kv_write`).
+
+Read from the profiler trace by `harness.scopes`: leaf ops that start
+inside a forward, each under the innermost listed scope on its op_name.
+None where no op carries a scope."""
+
+from harness.scopes import scope_share
+
+
+def read(ctx):
+    return scope_share(ctx, "kv_write")

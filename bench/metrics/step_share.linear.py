@@ -1,0 +1,13 @@
+"""Share of the forwards' device time spent in the linear sites: LUT
+kernels or dense matmuls, with what XLA fuses into them (scope
+`linear`).
+
+Read from the profiler trace by `harness.scopes`: leaf ops that start
+inside a forward, each under the innermost listed scope on its op_name.
+None where no op carries a scope."""
+
+from harness.scopes import scope_share
+
+
+def read(ctx):
+    return scope_share(ctx, "linear")

@@ -48,6 +48,7 @@ def _encode_call(x_sub, centroids, *, block_n, block_c, interpret):
         out_specs=pl.BlockSpec((bn, bc), lambda i, cc: (i, cc)),
         out_shape=jax.ShapeDtypeStruct((np_, c), jnp.int32),
         interpret=interpret,
+        name="dist_argmin",
     )(x_sub, centroids.astype(jnp.float32))
 
 

@@ -176,6 +176,7 @@ def _fused_decode_call(
         out_shape=jax.ShapeDtypeStruct((np_, mp_), jnp.float32),
         scratch_shapes=[pltpu.VMEM(code_shape, jnp.int8)],
         interpret=interpret,
+        name="lut_fused_decode",
     )(*operands)
 
 

@@ -200,6 +200,7 @@ def _lut_amm_call_v2(
         out_shape=jax.ShapeDtypeStruct((np_, mp_), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bn, bm), acc_dtype)],
         interpret=interpret,
+        name="lut_amm_v2",
     )(*operands)
 
 
@@ -334,6 +335,7 @@ def lut_amm_pallas_v1(
         out_specs=pl.BlockSpec((bn, bm), lambda i, j, cc: (i, j)),
         out_shape=jax.ShapeDtypeStruct((np_, mp_), jnp.float32),
         interpret=interpret,
+        name="lut_amm_v1",
     )(x_sub, centroids.astype(jnp.float32), tp, sp)
 
     return out[:n, :m].astype(x.dtype)

@@ -260,6 +260,7 @@ def paged_gather(pool: jax.Array, block_tables: jax.Array) -> jax.Array:
     return g.reshape(b, p * pool.shape[1], *pool.shape[2:])
 
 
+@jax.named_scope("attention")
 def attention(
     cfg: AttnCfg,
     p: Params,
@@ -287,6 +288,9 @@ def attention(
     block table (invalid positions land in the garbage page), reads gather
     the row's pages back into the dense logical layout and reuse the exact
     dense masks, so outputs are bit-identical to the dense cache path.
+
+    Its ops sit under the named scope `attention`, its cache writes under
+    `attention/kv_write`, and its projections under `attention/linear`.
     """
     b, s, _ = x.shape
     src = x if x_kv is None else x_kv
@@ -349,12 +353,13 @@ def attention(
         n_pages, page_size = cache["k_pool"].shape[:2]
         flat = paged_write_flat(block_tables, cache_len, s, page_size, write_len)
         flat_shape = (n_pages * page_size, cfg.n_kv_heads, cfg.d_head)
-        ck = (cache["k_pool"].reshape(flat_shape)
-              .at[flat].set(k.astype(cache["k_pool"].dtype))
-              .reshape(cache["k_pool"].shape))
-        cv = (cache["v_pool"].reshape(flat_shape)
-              .at[flat].set(v.astype(cache["v_pool"].dtype))
-              .reshape(cache["v_pool"].shape))
+        with jax.named_scope("kv_write"):
+            ck = (cache["k_pool"].reshape(flat_shape)
+                  .at[flat].set(k.astype(cache["k_pool"].dtype))
+                  .reshape(cache["k_pool"].shape))
+            cv = (cache["v_pool"].reshape(flat_shape)
+                  .at[flat].set(v.astype(cache["v_pool"].dtype))
+                  .reshape(cache["v_pool"].shape))
         new_cache = {"k_pool": ck, "v_pool": cv}
         s_logical = block_tables.shape[1] * page_size
         all_pos = jnp.arange(s_logical, dtype=jnp.int32)[None, :].repeat(b, 0)
@@ -394,8 +399,9 @@ def attention(
         s_max = cache["k"].shape[1]
         write_idx = (cache_len[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :])  # (B, S)
         bidx = jnp.arange(b, dtype=jnp.int32)[:, None]
-        ck = cache["k"].at[bidx, write_idx].set(k.astype(cache["k"].dtype))
-        cv = cache["v"].at[bidx, write_idx].set(v.astype(cache["v"].dtype))
+        with jax.named_scope("kv_write"):
+            ck = cache["k"].at[bidx, write_idx].set(k.astype(cache["k"].dtype))
+            cv = cache["v"].at[bidx, write_idx].set(v.astype(cache["v"].dtype))
         new_cache = {"k": ck, "v": cv}
         all_pos = jnp.arange(s_max, dtype=jnp.int32)[None, :].repeat(b, 0)
         valid = all_pos < (cache_len + s)[:, None]

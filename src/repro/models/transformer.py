@@ -234,34 +234,36 @@ def _seg_apply(
     xs = seg_params if caches is None else (seg_params, caches)
     (x, aux), new_caches = jax.lax.scan(fn, (x, jnp.zeros((), jnp.float32)), xs)
     if defer and new_caches is not None:
-        b = x.shape[0]
-        s_new = new_caches["k_slab"].shape[2]
-        if "k_pool" in caches:
-            # paged: one O(L*B*s_new) scatter into the page-flattened pool;
-            # masked rows route to the garbage page (no select-merge needed)
-            n_pages, page_size = caches["k_pool"].shape[1:3]
-            wl = write_len
-            if wl is None:
-                wl = jnp.full((b,), s_new, jnp.int32)
-            flat = attn_mod.paged_write_flat(
-                block_tables, cache_len, s_new, page_size, wl)          # (B, s)
+        with jax.named_scope("kv_write"):
+            b = x.shape[0]
+            s_new = new_caches["k_slab"].shape[2]
+            if "k_pool" in caches:
+                # paged: one O(L*B*s_new) scatter into the page-flattened pool;
+                # masked rows route to the garbage page (no select-merge needed)
+                n_pages, page_size = caches["k_pool"].shape[1:3]
+                wl = write_len
+                if wl is None:
+                    wl = jnp.full((b,), s_new, jnp.int32)
+                flat = attn_mod.paged_write_flat(
+                    block_tables, cache_len, s_new, page_size, wl)          # (B, s)
 
-            def scatter(pool, slab):
-                fp = pool.reshape(pool.shape[0], n_pages * page_size, *pool.shape[3:])
-                return fp.at[:, flat].set(slab).reshape(pool.shape)
+                def scatter(pool, slab):
+                    fp = pool.reshape(pool.shape[0], n_pages * page_size, *pool.shape[3:])
+                    return fp.at[:, flat].set(slab).reshape(pool.shape)
 
-            new_caches = {
-                "k_pool": scatter(caches["k_pool"], new_caches["k_slab"]),
-                "v_pool": scatter(caches["v_pool"], new_caches["v_slab"]),
-            }
-        else:
-            write_idx = cache_len[:, None] + jnp.arange(s_new, dtype=jnp.int32)[None, :]  # (B, s)
-            bidx = jnp.arange(b, dtype=jnp.int32)[:, None]                                # (B, 1)
-            # one O(L*B*s_new) scatter replaces L full-cache functional rewrites
-            new_caches = {
-                "k": caches["k"].at[:, bidx, write_idx].set(new_caches["k_slab"]),
-                "v": caches["v"].at[:, bidx, write_idx].set(new_caches["v_slab"]),
-            }
+                new_caches = {
+                    "k_pool": scatter(caches["k_pool"], new_caches["k_slab"]),
+                    "v_pool": scatter(caches["v_pool"], new_caches["v_slab"]),
+                }
+            else:
+                # write positions (B, s) and slot rows (B, 1)
+                write_idx = cache_len[:, None] + jnp.arange(s_new, dtype=jnp.int32)[None, :]
+                bidx = jnp.arange(b, dtype=jnp.int32)[:, None]
+                # one O(L*B*s_new) scatter replaces L full-cache functional rewrites
+                new_caches = {
+                    "k": caches["k"].at[:, bidx, write_idx].set(new_caches["k_slab"]),
+                    "v": caches["v"].at[:, bidx, write_idx].set(new_caches["v_slab"]),
+                }
     return x, new_caches, aux
 
 
@@ -299,15 +301,16 @@ def lm_apply(
         aux_total = aux_total + aux
 
     x = rmsnorm(params["final_norm"], x)
-    if cfg.lm_head is not None:
-        from repro.models.common import set_tape_prefix
+    with jax.named_scope("lm_head"):
+        if cfg.lm_head is not None:
+            from repro.models.common import set_tape_prefix
 
-        set_tape_prefix("")                 # registry key: bare "lm_head"
-        logits = linear(cfg.lm_head, params["lm_head"], x)
-    else:
-        logits = jnp.einsum(
-            "bsd,vd->bsv", x, params["embed"]["table"].astype(x.dtype)
-        )
+            set_tape_prefix("")                 # registry key: bare "lm_head"
+            logits = linear(cfg.lm_head, params["lm_head"], x)
+        else:
+            logits = jnp.einsum(
+                "bsd,vd->bsv", x, params["embed"]["table"].astype(x.dtype)
+            )
     return logits, new_caches, aux_total
 
 

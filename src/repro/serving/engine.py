@@ -23,8 +23,14 @@ by batched KV caches. Scheduler state machine (DESIGN.md §6):
   against max_tokens/EOS immediately, so `max_tokens=1` returns exactly one
   token.
 * **Observability** — `stats()` reports prefill/decode token and forward
-  counts, wall-clock split, mean decode batch occupancy, and token-shape
-  cache hits.
+  counts, wall-clock split (each step's wall time `step_s` against its
+  forwards' `prefill_s`/`decode_s`), queue wait from submit to admission,
+  mean decode batch occupancy, token-shape cache hits and the XLA
+  compilations since the last reset. Each step writes host spans on the
+  profiler's clock (`engine.step`, `engine.admit`, `engine.prefill`,
+  `engine.decode`, their `*.forward` dispatch-to-ready intervals,
+  `engine.sample`); their attributes (request ids, rows) are formatted only
+  while a trace is being recorded.
 * **Request lifecycle** (DESIGN.md §11.1) — every request resolves to a
   terminal `status` in {ok, timeout, cancelled, shed, error}. `submit()`
   takes a `priority` and a relative `deadline_s`; expired or cancelled
@@ -70,6 +76,7 @@ sequential, so mamba-family bundles need chunk-aligned prompts.
 from __future__ import annotations
 
 import dataclasses
+import threading
 import time
 import warnings
 from collections import deque
@@ -97,6 +104,34 @@ KV_DTYPES = {
     "float8_e4m3fn": jnp.float8_e4m3fn,
     "float8_e5m2": jnp.float8_e5m2,
 }
+
+
+_COMPILE_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_compiles = 0
+_compiles_lock = threading.Lock()
+
+
+def _on_compile(event: str, duration: float, **_: Any) -> None:
+    global _compiles
+    if event == _COMPILE_EVENT:
+        with _compiles_lock:
+            _compiles += 1
+
+
+# one listener for the process (JAX cannot remove one): it sees every
+# program newly lowered for XLA, by any caller
+jax.monitoring.register_event_duration_secs_listener(_on_compile)
+
+
+def compile_count() -> int:
+    """XLA compilations in this process since this module was loaded."""
+    return _compiles
+
+
+def _rids(reqs: list[Any]) -> str:
+    """Request ids as a span attribute (no commas: the profiler splits
+    attributes on them)."""
+    return " ".join(str(r.rid) for r in reqs)
 
 
 def _is_pool_leaf(path) -> bool:
@@ -212,6 +247,7 @@ class Request:
     status: str = "ok"
     n_prefilled: int = 0     # prompt tokens already consumed by chunk forwards
     submit_t: float = 0.0    # time.monotonic() at submit
+    admit_t: float = 0.0     # time.monotonic() when it took a slot
     finish_t: float = 0.0    # time.monotonic() at terminal transition
     cancel_requested: bool = False
     spec_decode: bool | None = None   # per-request override; None = engine default
@@ -421,7 +457,8 @@ class ServingEngine:
                 m = slot_mask.reshape(shape)
                 return jnp.where(m, new, old)
 
-            merged = jax.tree_util.tree_map_with_path(merge, caches, new_caches)
+            with jax.named_scope("kv_write"):
+                merged = jax.tree_util.tree_map_with_path(merge, caches, new_caches)
             return logits, merged
 
         # one jitted row-masked forward serves both phases; the two token
@@ -476,12 +513,29 @@ class ServingEngine:
             "error": 0,
             # prompt tokens satisfied from the prefix cache (never forwarded)
             "prefill_tokens_skipped": 0,
+            "admitted": 0,                # requests that took a slot
+            "queue_wait_s": 0.0,          # sum of admit_t - submit_t over them
+            "step_s": 0.0,                # wall time of step(), forwards included
+            # a front end's wait for its lock before each submit
+            # (EnginePump.submit -> count_submit_wait)
+            "submits": 0,
+            "submit_wait_s": 0.0,
+            "submit_wait_max_s": 0.0,
         }
+        self._compiles_at_reset = compile_count()
         self._shapes_seen: set[tuple[Any, ...]] = set()
         if self.paged:
             self.pool.reset_counters()
         if getattr(self, "spec", None) is not None:
             self.spec.reset_counters()
+
+    def count_submit_wait(self, wait_s: float) -> None:
+        """Count one submit and how long its caller waited for the engine
+        (a front end's lock); zeroed with the other counters."""
+        c = self._counters
+        c["submits"] += 1
+        c["submit_wait_s"] += wait_s
+        c["submit_wait_max_s"] = max(c["submit_wait_max_s"], wait_s)
 
     def stats(self) -> dict[str, Any]:
         """Scheduler counters since construction / the last reset_stats()."""
@@ -497,6 +551,8 @@ class ServingEngine:
         c["prefill_tok_s"] = c["prefill_tokens"] / c["prefill_s"] if c["prefill_s"] else 0.0
         c["decode_tok_s"] = c["decode_tokens"] / c["decode_s"] if c["decode_s"] else 0.0
         c["lut_shapes_tuned"] = self.n_lut_shapes_tuned
+        # process-wide: compilations by any caller since reset_stats()
+        c["compiles"] = compile_count() - self._compiles_at_reset
         if self.paged:
             # pool gauges (DESIGN.md §12.4) — numeric, so server.py /metrics
             # exports each as lutnn_serving_<key> with no extra wiring
@@ -716,25 +772,33 @@ class ServingEngine:
         final prompt token must still run one forward to produce the first
         output logits); its re-write into the shared final page is what
         exercises copy-on-write end-to-end."""
-        for i in range(self.n_slots):
-            if self.slots[i] is None and self.queue:
-                req = max(self.queue, key=lambda r: (r.priority, -r.rid))
-                self.queue.remove(req)
-                self.slots[i] = req
-                self.cache_len[i] = 0
-                if self.spec is not None:
-                    self.spec.reset_slot(i)
-                if self.paged:
-                    pages = self.pool.lookup_prefix(req.prompt)
-                    shared = len(pages) * self.pool.page_size
-                    if shared >= len(req.prompt):
-                        shared = len(req.prompt) - 1
-                    self.slot_pages[i] = pages
-                    self.block_tables[i, :] = 0
-                    self.block_tables[i, : len(pages)] = pages
-                    req.n_prefilled = shared
-                    self.cache_len[i] = shared
-                    self._counters["prefill_tokens_skipped"] += shared
+        with jax.profiler.TraceAnnotation("engine.admit") as span:
+            admitted = []
+            for i in range(self.n_slots):
+                if self.slots[i] is None and self.queue:
+                    req = max(self.queue, key=lambda r: (r.priority, -r.rid))
+                    self.queue.remove(req)
+                    req.admit_t = time.monotonic()
+                    self._counters["admitted"] += 1
+                    self._counters["queue_wait_s"] += req.admit_t - req.submit_t
+                    admitted.append(req)
+                    self.slots[i] = req
+                    self.cache_len[i] = 0
+                    if self.spec is not None:
+                        self.spec.reset_slot(i)
+                    if self.paged:
+                        pages = self.pool.lookup_prefix(req.prompt)
+                        shared = len(pages) * self.pool.page_size
+                        if shared >= len(req.prompt):
+                            shared = len(req.prompt) - 1
+                        self.slot_pages[i] = pages
+                        self.block_tables[i, :] = 0
+                        self.block_tables[i, : len(pages)] = pages
+                        req.n_prefilled = shared
+                        self.cache_len[i] = shared
+                        self._counters["prefill_tokens_skipped"] += shared
+            if admitted and span.is_enabled():
+                span.set_metadata(rids=_rids(admitted))
 
     def _retire(self, slot: int, req: Request, status: str = "ok") -> None:
         req.done = True
@@ -846,19 +910,20 @@ class ServingEngine:
     def _sample(self, logits_rows: jax.Array) -> np.ndarray:
         """Batched sample over all n_slots rows; callers read only the rows
         of slots they own (other rows ride along with greedy defaults)."""
-        params = [
-            (self.slots[i].sampling if self.slots[i] is not None else GREEDY)
-            for i in range(self.n_slots)
-        ]
-        if all(p.greedy for p in params):
-            # hot default: skip the sort/softmax/categorical machinery —
-            # sample_tokens is argmax-identical for greedy rows
-            return np.asarray(jnp.argmax(logits_rows, axis=-1))
-        counters = [
-            len(self.slots[i].out_tokens) if self.slots[i] is not None else 0
-            for i in range(self.n_slots)
-        ]
-        return np.asarray(sample_tokens(logits_rows, *batch_arrays(params, counters)))
+        with jax.profiler.TraceAnnotation("engine.sample"):
+            params = [
+                (self.slots[i].sampling if self.slots[i] is not None else GREEDY)
+                for i in range(self.n_slots)
+            ]
+            if all(p.greedy for p in params):
+                # hot default: skip the sort/softmax/categorical machinery —
+                # sample_tokens is argmax-identical for greedy rows
+                return np.asarray(jnp.argmax(logits_rows, axis=-1))
+            counters = [
+                len(self.slots[i].out_tokens) if self.slots[i] is not None else 0
+                for i in range(self.n_slots)
+            ]
+            return np.asarray(sample_tokens(logits_rows, *batch_arrays(params, counters)))
 
     def _check_done_after_token(self, slot: int, req: Request, tok: int) -> None:
         """Done-conditions run after EVERY sampled token — including the one
@@ -872,117 +937,129 @@ class ServingEngine:
     def _prefill_step(self) -> None:
         """One shared `(n_slots, prefill_chunk)` forward consuming the next
         chunk of every prefilling slot's prompt."""
-        chunk = self.prefill_chunk
-        pre = [
-            (i, r) for i, r in enumerate(self.slots)
-            if r is not None and not r.prefill_done
-        ]
-        if self.paged and pre:
-            # page allocation + COW before the forward; preparation for one
-            # slot can shed another (or itself) on pool exhaustion, so
-            # re-check slot ownership after the whole pass
+        with jax.profiler.TraceAnnotation("engine.prefill") as span:
+            chunk = self.prefill_chunk
+            pre = [
+                (i, r) for i, r in enumerate(self.slots)
+                if r is not None and not r.prefill_done
+            ]
+            if self.paged and pre:
+                # page allocation + COW before the forward; preparation for one
+                # slot can shed another (or itself) on pool exhaustion, so
+                # re-check slot ownership after the whole pass
+                for i, r in pre:
+                    if self.slots[i] is not r:
+                        continue
+                    n = min(chunk, len(r.prompt) - r.n_prefilled)
+                    self._prepare_slot_writes(i, n)
+                pre = [(i, r) for i, r in pre if self.slots[i] is r]
+                self._flush_copies()
+            if not pre:
+                return
+            if span.is_enabled():
+                span.set_metadata(rids=_rids([r for _, r in pre]))
+            toks = np.zeros((self.n_slots, chunk), np.int32)
+            cache_len = np.zeros((self.n_slots,), np.int32)
+            mask = np.zeros((self.n_slots,), bool)
+            write_len = np.zeros((self.n_slots,), np.int32)
+            n_new = {}
             for i, r in pre:
-                if self.slots[i] is not r:
-                    continue
-                n = min(chunk, len(r.prompt) - r.n_prefilled)
-                self._prepare_slot_writes(i, n)
-            pre = [(i, r) for i, r in pre if self.slots[i] is r]
-            self._flush_copies()
-        if not pre:
-            return
-        toks = np.zeros((self.n_slots, chunk), np.int32)
-        cache_len = np.zeros((self.n_slots,), np.int32)
-        mask = np.zeros((self.n_slots,), bool)
-        write_len = np.zeros((self.n_slots,), np.int32)
-        n_new = {}
-        for i, r in pre:
-            part = r.prompt[r.n_prefilled : r.n_prefilled + chunk]
-            toks[i, : len(part)] = part
-            cache_len[i] = r.n_prefilled
-            mask[i] = True
-            n_new[i] = len(part)
-            write_len[i] = len(part)
-        t0 = time.perf_counter()
-        step_args = (
-            self.params, jnp.asarray(toks), jnp.asarray(cache_len),
-            self.caches, jnp.asarray(mask),
-        )
-        if self.paged:
-            step_args += (jnp.asarray(self.block_tables), jnp.asarray(write_len))
-        logits, self.caches = self._step_fn(*step_args)
-        logits = jax.block_until_ready(logits)
-        self._record(toks)
-        self._counters["prefill_forwards"] += 1
-        self._counters["prefill_tokens"] += sum(n_new.values())
-        self._counters["prefill_s"] += time.perf_counter() - t0
-        if self.spec is not None:
-            # the draft's dense cache must see every prompt token: mirror
-            # the chunk with the SAME pre-update arrays the target consumed
-            self.spec.mirror_prefill(toks, cache_len, mask, write_len)
+                part = r.prompt[r.n_prefilled : r.n_prefilled + chunk]
+                toks[i, : len(part)] = part
+                cache_len[i] = r.n_prefilled
+                mask[i] = True
+                n_new[i] = len(part)
+                write_len[i] = len(part)
+            with jax.profiler.TraceAnnotation("engine.prefill.forward") as fwd:
+                t0 = time.perf_counter()
+                step_args = (
+                    self.params, jnp.asarray(toks), jnp.asarray(cache_len),
+                    self.caches, jnp.asarray(mask),
+                )
+                if self.paged:
+                    step_args += (jnp.asarray(self.block_tables), jnp.asarray(write_len))
+                logits, self.caches = self._step_fn(*step_args)
+                logits = jax.block_until_ready(logits)
+                self._record(toks)
+                self._counters["prefill_forwards"] += 1
+                self._counters["prefill_tokens"] += sum(n_new.values())
+                self._counters["prefill_s"] += time.perf_counter() - t0
+                if fwd.is_enabled():
+                    fwd.set_metadata(rows=len(pre))
+            if self.spec is not None:
+                # the draft's dense cache must see every prompt token: mirror
+                # the chunk with the SAME pre-update arrays the target consumed
+                self.spec.mirror_prefill(toks, cache_len, mask, write_len)
 
-        # sample the first output token for every slot whose prompt just
-        # completed, from that slot's last valid position in this chunk
-        last_idx = np.zeros((self.n_slots,), np.int32)
-        finishing = []
-        for i, r in pre:
-            r.n_prefilled += n_new[i]
-            self.cache_len[i] = r.n_prefilled
-            if self.paged:
-                self._register_prefixes(i, r)
-            if r.prefill_done:
-                last_idx[i] = n_new[i] - 1
-                finishing.append((i, r))
-        if not finishing:
-            return
-        rows = logits[jnp.arange(self.n_slots), jnp.asarray(last_idx)]
-        nxt = self._sample(rows)
-        for i, r in finishing:
-            tok = int(nxt[i])
-            r.out_tokens.append(tok)
-            self._check_done_after_token(i, r, tok)
+            # sample the first output token for every slot whose prompt just
+            # completed, from that slot's last valid position in this chunk
+            last_idx = np.zeros((self.n_slots,), np.int32)
+            finishing = []
+            for i, r in pre:
+                r.n_prefilled += n_new[i]
+                self.cache_len[i] = r.n_prefilled
+                if self.paged:
+                    self._register_prefixes(i, r)
+                if r.prefill_done:
+                    last_idx[i] = n_new[i] - 1
+                    finishing.append((i, r))
+            if not finishing:
+                return
+            rows = logits[jnp.arange(self.n_slots), jnp.asarray(last_idx)]
+            nxt = self._sample(rows)
+            for i, r in finishing:
+                tok = int(nxt[i])
+                r.out_tokens.append(tok)
+                self._check_done_after_token(i, r, tok)
 
     def _decode_step(self) -> None:
         """One `(n_slots, 1)` forward advancing every DECODE-phase slot."""
-        dec = [
-            (i, r) for i, r in enumerate(self.slots)
-            if r is not None and r.prefill_done
-        ]
-        if self.paged and dec:
+        with jax.profiler.TraceAnnotation("engine.decode") as span:
+            dec = [
+                (i, r) for i, r in enumerate(self.slots)
+                if r is not None and r.prefill_done
+            ]
+            if self.paged and dec:
+                for i, r in dec:
+                    if self.slots[i] is not r:
+                        continue
+                    self._prepare_slot_writes(i, 1)
+                dec = [(i, r) for i, r in dec if self.slots[i] is r]
+                self._flush_copies()
+            if not dec:
+                return
+            if span.is_enabled():
+                span.set_metadata(rids=_rids([r for _, r in dec]))
+            toks = np.zeros((self.n_slots, 1), np.int32)
+            mask = np.zeros((self.n_slots,), bool)
+            write_len = np.zeros((self.n_slots,), np.int32)
             for i, r in dec:
-                if self.slots[i] is not r:
-                    continue
-                self._prepare_slot_writes(i, 1)
-            dec = [(i, r) for i, r in dec if self.slots[i] is r]
-            self._flush_copies()
-        if not dec:
-            return
-        toks = np.zeros((self.n_slots, 1), np.int32)
-        mask = np.zeros((self.n_slots,), bool)
-        write_len = np.zeros((self.n_slots,), np.int32)
-        for i, r in dec:
-            toks[i, 0] = r.out_tokens[-1] if r.out_tokens else r.prompt[-1]
-            mask[i] = True
-            write_len[i] = 1
-        t0 = time.perf_counter()
-        step_args = (
-            self.params, jnp.asarray(toks), jnp.asarray(self.cache_len),
-            self.caches, jnp.asarray(mask),
-        )
-        if self.paged:
-            step_args += (jnp.asarray(self.block_tables), jnp.asarray(write_len))
-        logits, self.caches = self._step_fn(*step_args)
-        logits = jax.block_until_ready(logits)
-        self._record(toks)
-        self._counters["decode_forwards"] += 1
-        self._counters["decode_tokens"] += len(dec)
-        self._counters["decode_s"] += time.perf_counter() - t0
+                toks[i, 0] = r.out_tokens[-1] if r.out_tokens else r.prompt[-1]
+                mask[i] = True
+                write_len[i] = 1
+            with jax.profiler.TraceAnnotation("engine.decode.forward") as fwd:
+                t0 = time.perf_counter()
+                step_args = (
+                    self.params, jnp.asarray(toks), jnp.asarray(self.cache_len),
+                    self.caches, jnp.asarray(mask),
+                )
+                if self.paged:
+                    step_args += (jnp.asarray(self.block_tables), jnp.asarray(write_len))
+                logits, self.caches = self._step_fn(*step_args)
+                logits = jax.block_until_ready(logits)
+                self._record(toks)
+                self._counters["decode_forwards"] += 1
+                self._counters["decode_tokens"] += len(dec)
+                self._counters["decode_s"] += time.perf_counter() - t0
+                if fwd.is_enabled():
+                    fwd.set_metadata(rows=len(dec))
 
-        nxt = self._sample(logits[:, 0, :])
-        for i, r in dec:
-            self.cache_len[i] += 1
-            tok = int(nxt[i])
-            r.out_tokens.append(tok)
-            self._check_done_after_token(i, r, tok)
+            nxt = self._sample(logits[:, 0, :])
+            for i, r in dec:
+                self.cache_len[i] += 1
+                tok = int(nxt[i])
+                r.out_tokens.append(tok)
+                self._check_done_after_token(i, r, tok)
 
     def step(self) -> None:
         """One engine step: fault hook, lifecycle sweep, admit, one prefill
@@ -993,16 +1070,20 @@ class ServingEngine:
         The sweep runs before admission so expired/cancelled requests never
         consume a forward, and a freed slot is re-admitted the same step.
         """
-        if self.faults is not None:
-            self.faults.on_step()        # may sleep, or raise Injected{Fault,Kill}
-        self._counters["steps"] += 1
-        self._sweep()
-        self._admit()
-        self._prefill_step()
-        if self.spec is not None:
-            self.spec.decode_round()
-        else:
-            self._decode_step()
+        t0 = time.perf_counter()
+        with jax.profiler.StepTraceAnnotation(
+                "engine.step", step_num=self._counters["steps"]):
+            if self.faults is not None:
+                self.faults.on_step()    # may sleep, or raise Injected{Fault,Kill}
+            self._counters["steps"] += 1
+            self._sweep()
+            self._admit()
+            self._prefill_step()
+            if self.spec is not None:
+                self.spec.decode_round()
+            else:
+                self._decode_step()
+        self._counters["step_s"] += time.perf_counter() - t0
 
     def has_work(self) -> bool:
         return bool(self.queue) or any(s is not None for s in self.slots)

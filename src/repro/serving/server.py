@@ -24,7 +24,11 @@ queued, diffing per-request token output through `TokenTap`, and firing
 per-request event callbacks. The asyncio side bridges those callbacks into
 per-connection `asyncio.Queue`s via `call_soon_threadsafe`. All engine
 access (submit/cancel/step/stats) happens under one lock, preserving the
-engine's single-threaded discipline.
+engine's single-threaded discipline. How long `submit` waits for that lock
+is counted with the engine's counters (`submits`, `submit_wait_s`,
+`submit_wait_max_s` in `stats()`), and the pump thread is always inside one
+of the host spans `pump.step` (taking and holding the lock for one engine
+step), `pump.dispatch` or `pump.idle`.
 
 Graceful drain (SIGTERM or `FrontEnd.request_shutdown()`): stop admitting
 (readyz -> 503, /generate -> 503), let in-flight requests finish, then stop
@@ -46,6 +50,8 @@ import signal
 import threading
 import time
 from typing import Any, Callable
+
+import jax
 
 from repro.serving.engine import ServingEngine, TokenTap, submit_from_spec
 from repro.serving.faults import InjectedKill
@@ -89,7 +95,9 @@ class EnginePump:
         return self._dead is None and not self._stop
 
     def submit(self, spec: dict[str, Any], on_event: EventCallback | None = None) -> int:
+        t0 = time.perf_counter()
         with self._lock:
+            self.engine.count_submit_wait(time.perf_counter() - t0)
             if self._dead is not None:
                 raise RuntimeError(f"engine died: {self._dead!r}")
             rid = submit_from_spec(self.engine, spec)
@@ -133,20 +141,23 @@ class EnginePump:
 
     # -- pump loop ---------------------------------------------------------
     def _dispatch(self, events: list[tuple[int, tuple[str, Any]]]) -> None:
-        for rid, ev in events:
-            cb = self._subs.get(rid)
-            if cb is not None:
-                try:
-                    cb(ev)
-                except Exception:      # noqa: BLE001 — a bad subscriber
-                    pass               # must not kill the pump
-            if ev[0] == "done":
-                self._subs.pop(rid, None)
+        with jax.profiler.TraceAnnotation("pump.dispatch") as span:
+            if span.is_enabled():
+                span.set_metadata(events=len(events))
+            for rid, ev in events:
+                cb = self._subs.get(rid)
+                if cb is not None:
+                    try:
+                        cb(ev)
+                    except Exception:      # noqa: BLE001 — a bad subscriber
+                        pass               # must not kill the pump
+                if ev[0] == "done":
+                    self._subs.pop(rid, None)
 
     def _run(self) -> None:
         while not self._stop:
             out: list[tuple[int, tuple[str, Any]]] = []
-            with self._lock:
+            with jax.profiler.TraceAnnotation("pump.step"), self._lock:
                 work = self.engine.has_work()
                 if work and self._dead is None:
                     try:
@@ -163,8 +174,9 @@ class EnginePump:
                     out.append((req.rid, ("done", (req.status, req.out_tokens))))
             self._dispatch(out)
             if not work:
-                self._wake.wait(self._idle_wait_s)
-                self._wake.clear()
+                with jax.profiler.TraceAnnotation("pump.idle"):
+                    self._wake.wait(self._idle_wait_s)
+                    self._wake.clear()
 
 
 # ---------------------------------------------------------------------------

@@ -77,7 +77,7 @@ def _compile(one_chip, n, d, m, version, cfg):
         spec((c, k, m), jnp.int8), spec((1, 1, m), jnp.float32),
     )
     assert "tpu_custom_call" in lowered.as_text()
-    lowered.compile()
+    return lowered.compile()
 
 
 @pytest.mark.parametrize("phase", sorted(TOKENS))
@@ -105,3 +105,31 @@ def test_kernels_compile_under_highest_default_precision(one_chip, version):
     cfg = autotune.BlockConfig(SLOTS, 512, d // ARCH.lut_v)
     with jax.default_matmul_precision("highest"):
         _compile(one_chip, SLOTS, d, m, version, cfg)
+
+
+@pytest.mark.parametrize("version,name", [(1, "lut_amm_v1"), (2, "lut_amm_v2"),
+                                          (autotune.VERSION_FUSED, "lut_fused_decode")])
+def test_kernel_calls_carry_their_stable_names(one_chip, version, name):
+    """A trace names each kernel call by its instruction: the kernel's own
+    name, whatever function or module wraps it."""
+    d, m = ARCH.d_model, ARCH.d_model
+    cfg = autotune.BlockConfig(SLOTS, 512, d // ARCH.lut_v)
+    calls = _kernel_calls(_compile(one_chip, SLOTS, d, m, version, cfg).as_text())
+    assert calls and all(c.split(".")[0] == name for c in calls), calls
+
+
+def test_the_encode_kernel_carries_its_stable_name(one_chip):
+    from repro.kernels import dist_argmin
+
+    c, k, v = ARCH.d_model // ARCH.lut_v, ARCH.lut_k, ARCH.lut_v
+    x = jax.ShapeDtypeStruct((SLOTS, ARCH.d_model), jnp.float32, sharding=one_chip)
+    p = jax.ShapeDtypeStruct((c, k, v), jnp.float32, sharding=one_chip)
+    text = jax.jit(lambda a, b: dist_argmin.encode_pallas(a, b)).lower(x, p).compile().as_text()
+    calls = _kernel_calls(text)
+    assert calls and all(c.split(".")[0] == "dist_argmin" for c in calls), calls
+
+
+def _kernel_calls(text: str) -> list[str]:
+    """Instruction names of the Pallas kernel calls in compiled HLO text."""
+    return [line.split("=", 1)[0].split()[-1].lstrip("%") for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
