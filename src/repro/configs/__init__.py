@@ -631,27 +631,36 @@ class ModelBundle:
 
         batch: tokens/embeds (+ optional frames for encdec prefill),
         cache_len (B,); paged caches additionally take block_tables (B, P)
-        and write_len (B,). Returns (logits for the new positions, new
+        and write_len (B,). With slot_mask (B,) in the batch, every cache
+        row of a slot it leaves out comes back bit-identical: dense and
+        per-slot leaves are written only in its rows (paged pools are
+        masked by write_len). Returns (logits for the new positions, new
         caches).
         """
         cache_len = batch["cache_len"]
         block_tables = batch.get("block_tables")
         write_len = batch.get("write_len")
+        slot_mask = batch.get("slot_mask")
         if self.kind == "encdec":
             caches = dict(caches)
             if "frames" in batch:                      # prefill: run encoder
                 enc_out = encdec_mod.encode(self.cfg, params, batch["frames"],
                                             compute_dtype=compute_dtype)
-                caches["cross"] = jax.tree.map(
+                cross = jax.tree.map(
                     lambda a: a.astype(compute_dtype),
                     encdec_mod.cross_kv(self.cfg, params, enc_out),
                 )
+                if slot_mask is not None:               # cross leaves: (L, B, ...)
+                    m = slot_mask[None, :, None, None, None]
+                    cross = jax.tree.map(lambda new, old: jnp.where(m, new, old),
+                                         cross, caches["cross"])
+                caches["cross"] = cross
             b, s = batch["tokens"].shape
             pos = cache_len[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]
             logits, new_caches = encdec_mod.decode(
                 self.cfg, params, tokens=batch["tokens"], pos=pos,
                 caches=caches, cache_len=cache_len, compute_dtype=compute_dtype,
-                block_tables=block_tables, write_len=write_len,
+                block_tables=block_tables, write_len=write_len, slot_mask=slot_mask,
             )
             return logits, new_caches
 
@@ -661,7 +670,7 @@ class ModelBundle:
             logits, new_caches, _ = hybrid_mod.hybrid_apply(
                 self.cfg, params, tokens=batch["tokens"], pos=pos,
                 caches=caches, cache_len=cache_len, compute_dtype=compute_dtype,
-                block_tables=block_tables, write_len=write_len,
+                block_tables=block_tables, write_len=write_len, slot_mask=slot_mask,
             )
             return logits, new_caches
 
@@ -675,7 +684,7 @@ class ModelBundle:
         logits, new_caches, _ = tf_mod.lm_apply(
             self.cfg, params, tokens=tok, embeds=emb, pos=pos,
             caches=caches, cache_len=cache_len, compute_dtype=compute_dtype,
-            block_tables=block_tables, write_len=write_len,
+            block_tables=block_tables, write_len=write_len, slot_mask=slot_mask,
         )
         return logits, new_caches
 

@@ -177,6 +177,28 @@ def flash_attention(
     return out.astype(q.dtype)
 
 
+def write_rows(
+    cache: jax.Array,            # (B, S, KV, Dh), or (L, B, S, KV, Dh) with `layer`
+    rows: jax.Array,             # (B, s, KV, Dh) new positions of each row
+    start: jax.Array,            # (B,) position of each row's first new one
+    keep: jax.Array | None,      # (B,) bool rows written; None: all
+    layer: jax.Array | None = None,
+) -> jax.Array:
+    """Scatter each row's new positions into `cache` (slice `layer` of a
+    layer-stacked one). Rows `keep` leaves out are routed past the end, and
+    they and every position at or past S are dropped: those cache rows stay
+    bit-identical. Only the new positions are written, so a donated cache
+    is updated in place."""
+    b, s = rows.shape[:2]
+    pos = start[:, None] + jnp.arange(s, dtype=start.dtype)[None, :]     # (B, s)
+    if keep is not None:
+        pos = jnp.where(keep[:, None], pos, cache.shape[-3])
+    idx = (jnp.arange(b, dtype=start.dtype)[:, None], pos)
+    if layer is not None:
+        idx = (layer,) + idx
+    return cache.at[idx].set(rows.astype(cache.dtype), mode="drop")
+
+
 def init_cache(b: int, s_max: int, cfg: AttnCfg, dtype=jnp.bfloat16) -> Params:
     return {
         "k": jnp.zeros((b, s_max, cfg.n_kv_heads, cfg.d_head), dtype),
@@ -274,20 +296,28 @@ def attention(
     defer_cache_write: bool = False,
     block_tables: jax.Array | None = None,  # (B, P) page ids (paged cache)
     write_len: jax.Array | None = None,     # (B,) valid fresh tokens per row
+    slot_mask: jax.Array | None = None,     # (B,) rows whose dense cache is written
+    layer: jax.Array | None = None,         # dense cache leaves are layer-stacked
 ) -> tuple[jax.Array, Params | None]:
     """Returns (output (B, S, D), updated cache).
 
     defer_cache_write (decode fast path, section Perf): attend over the
-    STALE cache and the fresh K/V slab as two flash partials and return
-    {"k_slab", "v_slab"} instead of a rewritten cache — the caller scatters
-    all layers' slabs into the stacked cache in one O(tokens) write, so the
-    per-layer functional cache copy disappears from the scan.
+    STALE cache and the fresh K/V slab as two flash partials. A paged cache
+    returns {"k_slab", "v_slab"} instead of a rewritten pool — the caller
+    scatters all layers' slabs into the pool in one O(tokens) write after
+    its scan; a dense cache is written in place first, as in every forward.
 
     Paged caches ({"k_pool", "v_pool"}, DESIGN.md §12) route through the
     same entry point: writes scatter into the page-flattened pool via the
     block table (invalid positions land in the garbage page), reads gather
     the row's pages back into the dense logical layout and reuse the exact
     dense masks, so outputs are bit-identical to the dense cache path.
+
+    A dense cache is written only in the rows `slot_mask` keeps (all rows
+    when it is None); every other row stays bit-identical. With `layer`
+    given, the dense cache leaves are (L, B, S_max, KV, Dh): this layer's
+    new rows go into slice `layer` in place, and attention reads that
+    slice. Paged writes are masked by `write_len` instead.
 
     Its ops sit under the named scope `attention`, its cache writes under
     `attention/kv_write`, and its projections under `attention/linear`.
@@ -373,45 +403,44 @@ def attention(
             causal=cfg.causal,
             kv_valid=valid,
         )
-    elif defer_cache_write:
-        # flash-decoding over (stale cache) + (fresh slab), no cache rewrite
-        s_max = cache["k"].shape[1]
-        kvh, g = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads
-        qg = q.reshape(b, s, kvh, g, cfg.d_head)
-        all_pos = jnp.arange(s_max, dtype=jnp.int32)[None, :].repeat(b, 0)
-        stale_valid = all_pos < cache_len[:, None]
-        part_cache = _attend_stats(
-            qg, cache["k"], cache["v"],
-            q_pos=flat_pos, kv_pos=all_pos, causal=cfg.causal, kv_valid=stale_valid,
-        )
-        slab_pos = (cache_len[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :])
-        part_slab = _attend_stats(
-            qg, k, v, q_pos=flat_pos, kv_pos=slab_pos, causal=cfg.causal, kv_valid=None,
-        )
-        out = _merge_stats([part_cache, part_slab]).reshape(b, s, cfg.n_heads, cfg.d_head)
-        out = out.astype(q.dtype)
-        new_cache = {
-            "k_slab": k.astype(cache["k"].dtype),
-            "v_slab": v.astype(cache["v"].dtype),
-        }
     else:
-        # scatter new K/V at per-sequence cursors, then attend over the cache
-        s_max = cache["k"].shape[1]
-        write_idx = (cache_len[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :])  # (B, S)
-        bidx = jnp.arange(b, dtype=jnp.int32)[:, None]
+        # write new K/V at per-sequence cursors in place, then read the
+        # cache back and attend over it
         with jax.named_scope("kv_write"):
-            ck = cache["k"].at[bidx, write_idx].set(k.astype(cache["k"].dtype))
-            cv = cache["v"].at[bidx, write_idx].set(v.astype(cache["v"].dtype))
-        new_cache = {"k": ck, "v": cv}
+            new_cache = {
+                name: write_rows(cache[name], new, cache_len, slot_mask, layer)
+                for name, new in (("k", k), ("v", v))
+            }
+        ck, cv = new_cache["k"], new_cache["v"]
+        if layer is not None:
+            ck = jax.lax.dynamic_index_in_dim(ck, layer, keepdims=False)
+            cv = jax.lax.dynamic_index_in_dim(cv, layer, keepdims=False)
+        s_max = ck.shape[1]
         all_pos = jnp.arange(s_max, dtype=jnp.int32)[None, :].repeat(b, 0)
-        valid = all_pos < (cache_len + s)[:, None]
-        out = flash_attention(
-            q, ck, cv,
-            q_pos=flat_pos,
-            kv_pos=all_pos,
-            causal=cfg.causal,
-            kv_valid=valid,
-        )
+        if defer_cache_write:
+            # flash-decoding over (stale cache) + (fresh slab): the stale
+            # partial masks every position from cache_len on, the new
+            # rows included
+            kvh, g = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads
+            qg = q.reshape(b, s, kvh, g, cfg.d_head)
+            part_cache = _attend_stats(
+                qg, ck, cv, q_pos=flat_pos, kv_pos=all_pos, causal=cfg.causal,
+                kv_valid=all_pos < cache_len[:, None],
+            )
+            slab_pos = (cache_len[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :])
+            part_slab = _attend_stats(
+                qg, k, v, q_pos=flat_pos, kv_pos=slab_pos, causal=cfg.causal, kv_valid=None,
+            )
+            out = _merge_stats([part_cache, part_slab]).reshape(b, s, cfg.n_heads, cfg.d_head)
+            out = out.astype(q.dtype)
+        else:
+            out = flash_attention(
+                q, ck, cv,
+                q_pos=flat_pos,
+                kv_pos=all_pos,
+                causal=cfg.causal,
+                kv_valid=all_pos < (cache_len + s)[:, None],
+            )
 
     y = linear(cfg.o, p["o"], out.reshape(b, s, cfg.n_heads * cfg.d_head))
     return y, new_cache

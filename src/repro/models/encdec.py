@@ -162,12 +162,12 @@ def _cross_attend(a: AttnCfg, pl_: Params, x: jax.Array, kv: Params) -> jax.Arra
 def _dec_block(
     cfg: EncDecCfg, pl_: Params, x: jax.Array, *,
     pos, self_cache, cache_len, cross: Params,
-    block_tables=None, write_len=None,
+    block_tables=None, write_len=None, slot_mask=None,
 ) -> tuple[jax.Array, Params | None]:
     a, new_cache = attn_mod.attention(
         cfg.dec_self, pl_["self"], rmsnorm(pl_["norm1"], x),
         pos=pos, cache=self_cache, cache_len=cache_len,
-        block_tables=block_tables, write_len=write_len,
+        block_tables=block_tables, write_len=write_len, slot_mask=slot_mask,
     )
     x = x + a
     x = x + _cross_attend(cfg.dec_cross, pl_["cross"], rmsnorm(pl_["norm2"], x), cross)
@@ -187,6 +187,7 @@ def decode(
     compute_dtype=jnp.float32,
     block_tables: jax.Array | None = None,
     write_len: jax.Array | None = None,
+    slot_mask: jax.Array | None = None,
 ) -> tuple[jax.Array, Params | None]:
     x = embed(params["embed"], tokens).astype(compute_dtype)
 
@@ -215,7 +216,8 @@ def decode(
         def body(xc, layer_in):
             pl_, sc, cr = layer_in
             y, nc = _dec_block(cfg, pl_, xc, pos=pos, self_cache=sc, cache_len=cache_len,
-                               cross=cr, block_tables=block_tables, write_len=write_len)
+                               cross=cr, block_tables=block_tables, write_len=write_len,
+                               slot_mask=slot_mask)
             return y, nc
 
         x, new_self = jax.lax.scan(

@@ -106,13 +106,13 @@ def hybrid_caches(cfg: HybridCfg, b: int, s_max: int, dtype=jnp.bfloat16, abstra
 
 def _shared_block(
     cfg: HybridCfg, p: Params, x: jax.Array, x0: jax.Array, *,
-    pos, cache, cache_len, block_tables=None, write_len=None,
+    pos, cache, cache_len, block_tables=None, write_len=None, slot_mask=None,
 ) -> tuple[jax.Array, Params | None]:
     h = linear(cfg.fuse, p["fuse"], jnp.concatenate([x, x0], axis=-1))
     a, new_cache = attn_mod.attention(
         cfg.shared_attn, p["attn"], rmsnorm(p["norm1"], h),
         pos=pos, cache=cache, cache_len=cache_len,
-        block_tables=block_tables, write_len=write_len,
+        block_tables=block_tables, write_len=write_len, slot_mask=slot_mask,
     )
     h = h + a
     h = h + mlp_mod.mlp(cfg.shared_mlp, p["mlp"], rmsnorm(p["norm2"], h))
@@ -130,6 +130,7 @@ def hybrid_apply(
     compute_dtype=jnp.float32,
     block_tables: jax.Array | None = None,
     write_len: jax.Array | None = None,
+    slot_mask: jax.Array | None = None,
 ) -> tuple[jax.Array, Params | None, jax.Array]:
     x = embed(params["embed"], tokens).astype(compute_dtype)
     x0 = x
@@ -145,7 +146,8 @@ def hybrid_apply(
                 set_tape_prefix(f"mamba_stack/{lo + j}")
                 pl_ = jax.tree.map(lambda a: a[lo + j], params["mamba_stack"])
                 cl_ = None if cstack is None else jax.tree.map(lambda a: a[lo + j], cstack)
-                x, nc, _ = block_apply(cfg.mamba_block, pl_, x, pos=pos, cache=cl_)
+                x, nc, _ = block_apply(cfg.mamba_block, pl_, x, pos=pos, cache=cl_,
+                                       slot_mask=slot_mask)
                 if cstack is not None:
                     new_c.append(nc)
             if cstack is not None:
@@ -159,7 +161,8 @@ def hybrid_apply(
                 y, _, _ = block_apply(cfg.mamba_block, layer_in, xc, pos=pos)
                 return y, None
             pl_, cl_ = layer_in
-            y, nc, _ = block_apply(cfg.mamba_block, pl_, xc, pos=pos, cache=cl_)
+            y, nc, _ = block_apply(cfg.mamba_block, pl_, xc, pos=pos, cache=cl_,
+                                   slot_mask=slot_mask)
             return y, nc
 
         fn = jax.checkpoint(body) if (cfg.remat and cstack is None) else body
@@ -185,7 +188,7 @@ def hybrid_apply(
             x, nac = _shared_block(
                 cfg, params["shared"], x, x0,
                 pos=pos, cache=a_cache, cache_len=cache_len,
-                block_tables=block_tables, write_len=write_len,
+                block_tables=block_tables, write_len=write_len, slot_mask=slot_mask,
             )
             if caches is not None:
                 new_a.append(nac)

@@ -101,17 +101,28 @@ def block_apply(
     defer_cache_write: bool = False,
     block_tables: jax.Array | None = None,
     write_len: jax.Array | None = None,
+    slot_mask: jax.Array | None = None,
+    layer: jax.Array | None = None,
 ) -> tuple[jax.Array, Params | None, jax.Array]:
-    """Returns (x, new_cache, aux_loss)."""
+    """Returns (x, new_cache, aux_loss).
+
+    Cache rows outside `slot_mask` (when given) come back unchanged;
+    `layer` is attention's: its dense cache leaves are layer-stacked."""
     aux = jnp.zeros((), jnp.float32)
     if cfg.kind == "mamba":
         h, new_cache = mamba_mod.mamba2(cfg.mamba, p["mamba"], rmsnorm(p["norm"], x), cache=cache)
+        if cache is not None and slot_mask is not None:
+            new_cache = jax.tree.map(
+                lambda new, old: jnp.where(
+                    slot_mask.reshape((-1,) + (1,) * (old.ndim - 1)), new, old),
+                new_cache, cache)
         return x + h, new_cache, aux
 
     a, new_cache = attn_mod.attention(
         cfg.attn, p["attn"], rmsnorm(p["norm1"], x), pos=pos, cache=cache,
         cache_len=cache_len, defer_cache_write=defer_cache_write,
         block_tables=block_tables, write_len=write_len,
+        slot_mask=slot_mask, layer=layer,
     )
     x = x + a
     h = rmsnorm(p["norm2"], x)
@@ -188,6 +199,7 @@ def _seg_apply(
     prefix: str = "",
     block_tables: jax.Array | None = None,
     write_len: jax.Array | None = None,
+    slot_mask: jax.Array | None = None,
 ) -> tuple[jax.Array, Params | None, jax.Array]:
     """scan one segment of stacked layers."""
     if unroll:
@@ -203,7 +215,8 @@ def _seg_apply(
             set_tape_prefix(f"{prefix}/{j}")
             pl_ = jax.tree.map(lambda a: a[j], seg_params)
             cl_ = None if caches is None else jax.tree.map(lambda a: a[j], caches)
-            x, nc, a = block_apply(bcfg, pl_, x, pos=pos, cache=cl_, cache_len=cache_len)
+            x, nc, a = block_apply(bcfg, pl_, x, pos=pos, cache=cl_, cache_len=cache_len,
+                                   slot_mask=slot_mask)
             aux = aux + a
             if caches is not None:
                 new_caches.append(nc)
@@ -220,50 +233,58 @@ def _seg_apply(
         pl_, cl_ = layer_in
         y, new_c, a = block_apply(bcfg, pl_, xc, pos=pos, cache=cl_,
                                   cache_len=cache_len, defer_cache_write=defer,
-                                  block_tables=block_tables, write_len=write_len)
+                                  block_tables=block_tables, write_len=write_len,
+                                  slot_mask=slot_mask)
         return (y, aux + a), new_c
 
-    # decode fast path: attention layers return K/V slabs; one scatter into
-    # the stacked cache afterwards replaces per-layer cache rewrites
+    # decode fast path: attention attends over the stale cache plus the
+    # fresh K/V slab (flash-decoding)
     defer = (
         caches is not None
         and bcfg.kind != "mamba"
         and x.shape[1] == 1
     )
+    aux0 = jnp.zeros((), jnp.float32)
+    if caches is not None and "k" in caches:
+        # a dense attention cache rides the scan's carry: each layer writes
+        # its new rows into it in place, then reads them back, so the scan
+        # never builds a whole cache as its output
+        def carry_body(carry, layer_in):
+            xc, aux, kv = carry
+            pl_, j = layer_in
+            y, kv, a = block_apply(bcfg, pl_, xc, pos=pos, cache=kv, cache_len=cache_len,
+                                   defer_cache_write=defer, slot_mask=slot_mask, layer=j)
+            return (y, aux + a, kv), None
+
+        n_layers = jax.tree.leaves(seg_params)[0].shape[0]
+        (x, aux, new_caches), _ = jax.lax.scan(
+            carry_body, (x, aux0, caches),
+            (seg_params, jnp.arange(n_layers, dtype=jnp.int32)))
+        return x, new_caches, aux
     fn = jax.checkpoint(body) if (remat and caches is None) else body
     xs = seg_params if caches is None else (seg_params, caches)
-    (x, aux), new_caches = jax.lax.scan(fn, (x, jnp.zeros((), jnp.float32)), xs)
+    (x, aux), new_caches = jax.lax.scan(fn, (x, aux0), xs)
     if defer and new_caches is not None:
+        # paged: one O(L*B*s_new) scatter of every layer's slab into the
+        # page-flattened pool; masked rows route to the garbage page
         with jax.named_scope("kv_write"):
             b = x.shape[0]
             s_new = new_caches["k_slab"].shape[2]
-            if "k_pool" in caches:
-                # paged: one O(L*B*s_new) scatter into the page-flattened pool;
-                # masked rows route to the garbage page (no select-merge needed)
-                n_pages, page_size = caches["k_pool"].shape[1:3]
-                wl = write_len
-                if wl is None:
-                    wl = jnp.full((b,), s_new, jnp.int32)
-                flat = attn_mod.paged_write_flat(
-                    block_tables, cache_len, s_new, page_size, wl)          # (B, s)
+            n_pages, page_size = caches["k_pool"].shape[1:3]
+            wl = write_len
+            if wl is None:
+                wl = jnp.full((b,), s_new, jnp.int32)
+            flat = attn_mod.paged_write_flat(
+                block_tables, cache_len, s_new, page_size, wl)          # (B, s)
 
-                def scatter(pool, slab):
-                    fp = pool.reshape(pool.shape[0], n_pages * page_size, *pool.shape[3:])
-                    return fp.at[:, flat].set(slab).reshape(pool.shape)
+            def scatter(pool, slab):
+                fp = pool.reshape(pool.shape[0], n_pages * page_size, *pool.shape[3:])
+                return fp.at[:, flat].set(slab).reshape(pool.shape)
 
-                new_caches = {
-                    "k_pool": scatter(caches["k_pool"], new_caches["k_slab"]),
-                    "v_pool": scatter(caches["v_pool"], new_caches["v_slab"]),
-                }
-            else:
-                # write positions (B, s) and slot rows (B, 1)
-                write_idx = cache_len[:, None] + jnp.arange(s_new, dtype=jnp.int32)[None, :]
-                bidx = jnp.arange(b, dtype=jnp.int32)[:, None]
-                # one O(L*B*s_new) scatter replaces L full-cache functional rewrites
-                new_caches = {
-                    "k": caches["k"].at[:, bidx, write_idx].set(new_caches["k_slab"]),
-                    "v": caches["v"].at[:, bidx, write_idx].set(new_caches["v_slab"]),
-                }
+            new_caches = {
+                "k_pool": scatter(caches["k_pool"], new_caches["k_slab"]),
+                "v_pool": scatter(caches["v_pool"], new_caches["v_slab"]),
+            }
     return x, new_caches, aux
 
 
@@ -279,8 +300,10 @@ def lm_apply(
     compute_dtype=jnp.float32,
     block_tables: jax.Array | None = None,
     write_len: jax.Array | None = None,
+    slot_mask: jax.Array | None = None,
 ) -> tuple[jax.Array, list | None, jax.Array]:
-    """Returns (logits (B, S, vocab), new caches, aux loss)."""
+    """Returns (logits (B, S, vocab), new caches, aux loss). Cache rows
+    outside `slot_mask` (B,), when given, come back bit-identical."""
     if cfg.takes_embeds:
         x = embeds.astype(compute_dtype)
     else:
@@ -294,7 +317,7 @@ def lm_apply(
             bcfg, params["segments"][i], x,
             pos=pos, caches=c_i, cache_len=cache_len, remat=cfg.remat,
             unroll=cfg.unroll, prefix=f"segments/{i}",
-            block_tables=block_tables, write_len=write_len,
+            block_tables=block_tables, write_len=write_len, slot_mask=slot_mask,
         )
         if caches is not None:
             new_caches.append(nc)

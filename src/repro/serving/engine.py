@@ -8,7 +8,8 @@ by batched KV caches. Scheduler state machine (DESIGN.md §6):
 * **Batched admission** — every free slot is filled from the queue at the
   top of `step()`; all admitted (and still-prefilling) slots share ONE
   padded `(n_slots, prefill_chunk)` prefill forward per step, row-masked so
-  untouched slots' caches never move (select-merge on per-slot `cache_len`).
+  untouched slots' caches never move: the forward writes only the rows of
+  the slots it advances, in place in the caches it is given (DESIGN.md §6).
 * **Chunked prefill** — prompts longer than `prefill_chunk` consume exactly
   one fixed-size chunk per engine step, interleaved with the decode step of
   already-active slots, so decode latency stays bounded by one chunk
@@ -402,18 +403,7 @@ class ServingEngine:
             self.block_tables = np.zeros((n_slots, self.n_tables), np.int32)
             self.slot_pages: list[list[int]] = [[] for _ in range(n_slots)]
             self._pending_copies: list[tuple[int, int]] = []
-        self.caches = bundle.init_caches(
-            n_slots, max_seq, dtype=self.kv_dtype, paged=paged_spec
-        )
-        # bytes per pool page across all layers (0 when no attention leaves,
-        # e.g. a pure-SSM bundle) — drives the kv_bytes_* gauges
-        if self.paged:
-            pool_bytes = sum(
-                int(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize
-                for path, leaf in jax.tree_util.tree_flatten_with_path(self.caches)[0]
-                if _is_pool_leaf(path)
-            )
-            self._page_bytes = pool_bytes // n_pages
+        self._paged_spec = paged_spec
         if rules is not None:
             # place model state once at construction (DESIGN.md §6.4):
             # tables column-sharded / codebooks replicated per param_spec,
@@ -423,9 +413,20 @@ class ServingEngine:
             )
             self.params = jax.device_put(params, self._param_shardings)
             self._cache_shardings = rules.cache_shardings(
-                jax.eval_shape(lambda: self.caches), n_slots
+                bundle.init_caches(n_slots, max_seq, abstract=True,
+                                   dtype=self.kv_dtype, paged=paged_spec),
+                n_slots,
             )
-            self.caches = jax.device_put(self.caches, self._cache_shardings)
+        self.caches = self._new_caches()
+        # bytes per pool page across all layers (0 when no attention leaves,
+        # e.g. a pure-SSM bundle) — drives the kv_bytes_* gauges
+        if self.paged:
+            pool_bytes = sum(
+                int(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize
+                for path, leaf in jax.tree_util.tree_flatten_with_path(self.caches)[0]
+                if _is_pool_leaf(path)
+            )
+            self._page_bytes = pool_bytes // n_pages
         self.cache_len = np.zeros((n_slots,), np.int32)
         self.slots: list[Request | None] = [None] * n_slots
         self.queue: deque[Request] = deque()
@@ -438,31 +439,19 @@ class ServingEngine:
 
         def step_fn(params, tokens, cache_len, caches, slot_mask,
                     block_tables=None, write_len=None):
-            batch = {"tokens": tokens, "cache_len": cache_len}
+            # only the masked slots' cache rows advance: the model writes
+            # no other row (pool leaves: write_len routes the rest to the
+            # garbage page)
+            batch = {"tokens": tokens, "cache_len": cache_len, "slot_mask": slot_mask}
             if block_tables is not None:
                 batch["block_tables"] = block_tables
                 batch["write_len"] = write_len
-            logits, new_caches = bundle.forward_step(
-                params, batch, caches, compute_dtype=compute_dtype,
-            )
-            # merge: only the masked slots' cache rows advance. Pool leaves
-            # carry no slot axis — their writes are already masked in-kernel
-            # (invalid rows route to the garbage page), so they pass through.
-            def merge(path, old, new):
-                if _is_pool_leaf(path):
-                    return new
-                # every per-slot cache leaf is layer-stacked: (L, B, ...)
-                shape = [1] * old.ndim
-                shape[1] = n_slots
-                m = slot_mask.reshape(shape)
-                return jnp.where(m, new, old)
-
-            with jax.named_scope("kv_write"):
-                merged = jax.tree_util.tree_map_with_path(merge, caches, new_caches)
-            return logits, merged
+            return bundle.forward_step(params, batch, caches, compute_dtype=compute_dtype)
 
         # one jitted row-masked forward serves both phases; the two token
-        # shapes (chunk vs 1) are its only two compile-cache entries
+        # shapes (chunk vs 1) are its only two compile-cache entries. The
+        # caches are donated: the step writes its new rows into the buffers
+        # it is given, and a caller must not use the caches it passed in.
         if rules is not None:
             # explicit in/out shardings: token rows ride the slot axis, and
             # the caches keep their construction-time layout across steps so
@@ -477,9 +466,10 @@ class ServingEngine:
                 step_fn,
                 in_shardings=tuple(in_sh),
                 out_shardings=(logits_sh, self._cache_shardings),
+                donate_argnums=3,
             )
         else:
-            self._step_fn = jax.jit(step_fn)
+            self._step_fn = jax.jit(step_fn, donate_argnums=3)
 
         if spec_decode:
             # self-draft (no draft bundle) is valid: acceptance ~1.0, used
@@ -614,15 +604,43 @@ class ServingEngine:
         toks[0, : len(prompt)] = prompt
         mask = np.zeros((self.n_slots,), bool)
         mask[0] = True
-        caches = self.bundle.init_caches(self.n_slots, self.max_seq,
-                                         dtype=self.kv_dtype)
-        if self.rules is not None:
-            caches = jax.device_put(caches, self._cache_shardings)
         logits, _ = self._step_fn(
             self.params, jnp.asarray(toks),
-            jnp.zeros((self.n_slots,), jnp.int32), caches, jnp.asarray(mask),
+            jnp.zeros((self.n_slots,), jnp.int32), self._new_caches(), jnp.asarray(mask),
         )
         return logits[0, : len(prompt)]
+
+    def _new_caches(self) -> Any:
+        """Empty caches laid out as the step takes them."""
+        caches = self.bundle.init_caches(self.n_slots, self.max_seq,
+                                         dtype=self.kv_dtype, paged=self._paged_spec)
+        if self.rules is not None:
+            caches = jax.device_put(caches, self._cache_shardings)
+        return caches
+
+    def _forward(self, tokens: np.ndarray, cache_len: np.ndarray, mask: np.ndarray,
+                 write_len: np.ndarray) -> jax.Array:
+        """Run the jitted step over the engine's caches and keep the caches
+        it returns; the step consumes the ones it is given (donated).
+
+        A step that fails may have consumed them and handed nothing back:
+        every cached row is then lost, so the engine retires every live
+        request as "error" and starts again from empty caches."""
+        args = (self.params, jnp.asarray(tokens), jnp.asarray(cache_len),
+                self.caches, jnp.asarray(mask))
+        if self.paged:
+            args += (jnp.asarray(self.block_tables), jnp.asarray(write_len))
+        try:
+            logits, self.caches = self._step_fn(*args)
+        except Exception:
+            if any(leaf.is_deleted() for leaf in jax.tree.leaves(self.caches)):
+                self.abort_all("error")
+                if self.paged:
+                    self.pool = KVPagePool(self.pool.n_pages, self.pool.page_size,
+                                           prefix_sharing=self.pool.prefix_sharing)
+                self.caches = self._new_caches()
+            raise
+        return logits
 
     def submit(
         self,
@@ -972,13 +990,7 @@ class ServingEngine:
                 write_len[i] = len(part)
             with jax.profiler.TraceAnnotation("engine.prefill.forward") as fwd:
                 t0 = time.perf_counter()
-                step_args = (
-                    self.params, jnp.asarray(toks), jnp.asarray(cache_len),
-                    self.caches, jnp.asarray(mask),
-                )
-                if self.paged:
-                    step_args += (jnp.asarray(self.block_tables), jnp.asarray(write_len))
-                logits, self.caches = self._step_fn(*step_args)
+                logits = self._forward(toks, cache_len, mask, write_len)
                 logits = jax.block_until_ready(logits)
                 self._record(toks)
                 self._counters["prefill_forwards"] += 1
@@ -1039,13 +1051,7 @@ class ServingEngine:
                 write_len[i] = 1
             with jax.profiler.TraceAnnotation("engine.decode.forward") as fwd:
                 t0 = time.perf_counter()
-                step_args = (
-                    self.params, jnp.asarray(toks), jnp.asarray(self.cache_len),
-                    self.caches, jnp.asarray(mask),
-                )
-                if self.paged:
-                    step_args += (jnp.asarray(self.block_tables), jnp.asarray(write_len))
-                logits, self.caches = self._step_fn(*step_args)
+                logits = self._forward(toks, self.cache_len, mask, write_len)
                 logits = jax.block_until_ready(logits)
                 self._record(toks)
                 self._counters["decode_forwards"] += 1

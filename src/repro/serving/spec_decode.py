@@ -279,14 +279,7 @@ class SpecDecoder:
             cache_len[i] = s0[i]
             mask[i] = True
             write_len[i] = gam[i] + 1
-        step_args = (
-            eng.params, jnp.asarray(toks), jnp.asarray(cache_len),
-            eng.caches, jnp.asarray(mask),
-        )
-        if eng.paged:
-            step_args += (jnp.asarray(eng.block_tables), jnp.asarray(write_len))
-        logits, eng.caches = eng._step_fn(*step_args)
-        logits = jax.block_until_ready(logits)
+        logits = jax.block_until_ready(eng._forward(toks, cache_len, mask, write_len))
         eng._record(toks)
         self._c["spec_verify_forwards"] += 1
         eng._counters["decode_forwards"] += 1

@@ -228,3 +228,150 @@ def test_prefill_logits_match_train_logits(key, use_kernel):
     assert not eng.has_work() and eng.stats()["prefill_forwards"] == 0
     with pytest.raises(ValueError):
         eng.prefill_logits(list(range(1, 10)))          # longer than one chunk
+
+
+# --- the cache write: a forward writes only the rows it adds, in place --------
+
+def _model(mode):
+    """Three layers: the LUT plan's dense first layer is a segment of its
+    own, so both a one-layer and a multi-layer segment are written."""
+    bundle = build_model(reduce_arch(get_arch("qwen3_1p7b"), n_layers=3), mode)
+    return bundle, bundle.init(jax.random.PRNGKey(1))
+
+
+def _slot_rows(eng, slot):
+    """Host copies of every cached row of one slot, per cache leaf."""
+    return [np.array(leaf[:, slot]) for leaf in jax.tree.leaves(eng.caches)]
+
+
+def _same(a, b):
+    return all(np.array_equal(x, y) for x, y in zip(a, b))
+
+
+@pytest.mark.parametrize("mode", [Mode.DENSE, Mode.LUT_INFER])
+def test_a_forward_leaves_the_rows_of_slots_it_does_not_advance(mode):
+    eng = ServingEngine(*_model(mode), n_slots=3, max_seq=32, prefill_chunk=4,
+                        autotune_lut=False)
+    # two one-token requests retire in the first step and leave their rows
+    # behind; the third decodes on
+    for prompt, n in (([3, 5, 7], 1), ([2, 4, 6, 8], 1), ([9, 11], 8)):
+        eng.submit(prompt, max_tokens=n)
+    eng.step()
+    eng.submit(list(range(1, 8)), max_tokens=4)        # two chunks
+    eng._admit()
+    prefilling, idle, decoding = 0, 1, 2
+    assert eng.slots[idle] is None and not eng.slots[prefilling].prefill_done
+    assert eng.slots[decoding].prefill_done
+    rows = [_slot_rows(eng, i) for i in range(3)]
+    assert any(np.any(r != 0) for r in rows[idle])     # what it held stays
+
+    eng._prefill_step()                  # advances the prefilling slot only
+    after = [_slot_rows(eng, i) for i in range(3)]
+    assert _same(after[idle], rows[idle]) and _same(after[decoding], rows[decoding])
+    assert not _same(after[prefilling], rows[prefilling])
+
+    rows = after
+    eng._decode_step()                   # advances the decoding slot only
+    after = [_slot_rows(eng, i) for i in range(3)]
+    assert _same(after[idle], rows[idle]) and _same(after[prefilling], rows[prefilling])
+    assert not _same(after[decoding], rows[decoding])
+
+
+@pytest.mark.parametrize("tokens_per_row", [1, 4])
+@pytest.mark.parametrize("arch_id", ["qwen3_1p7b", "mamba2_370m", "zamba2_1p2b", "whisper_tiny"])
+def test_every_cache_family_writes_only_the_masked_slots(arch_id, tokens_per_row):
+    """forward_step with a slot mask: attention KV, SSM state and cross-KV
+    leaves of the slot it leaves out come back bit-identical; the kept
+    slot's are written."""
+    arch = reduce_arch(get_arch(arch_id))
+    bundle = build_model(arch, Mode.DENSE)
+    params = bundle.init(jax.random.PRNGKey(2))
+    rng = np.random.default_rng(0)
+    caches = jax.tree.map(lambda a: jnp.asarray(rng.standard_normal(a.shape), a.dtype),
+                          bundle.init_caches(2, 16, dtype=jnp.float32))
+    before = [np.array(a) for a in jax.tree.leaves(caches)]
+    batch = {"tokens": jnp.full((2, tokens_per_row), 3, jnp.int32),
+             "cache_len": jnp.asarray([4, 4], jnp.int32),
+             "slot_mask": jnp.asarray([True, False])}
+    if arch.family == "audio":
+        batch["frames"] = jnp.ones((2, arch.enc_frames, arch.d_model))
+    _, caches = bundle.forward_step(params, batch, caches, compute_dtype=jnp.float32)
+    after = [np.array(a) for a in jax.tree.leaves(caches)]
+    assert all(np.array_equal(a[:, 1], b[:, 1]) for a, b in zip(after, before))
+    assert all(not np.array_equal(a[:, 0], b[:, 0]) for a, b in zip(after, before))
+
+
+@pytest.mark.parametrize("past", [0, 2], ids=["ends_at_max_seq", "runs_past_it"])
+def test_a_chunk_at_the_end_of_the_cache_writes_only_its_own_rows(past):
+    """A chunk whose rows run past max_seq drops those rows; the ones that
+    fit land at their own positions, never shifted back onto valid rows."""
+    eng = ServingEngine(*_model(Mode.DENSE), n_slots=2, max_seq=16, prefill_chunk=4,
+                        autotune_lut=False)
+    rng = np.random.default_rng(0)
+    eng.caches = jax.tree.map(
+        lambda a: jnp.asarray(rng.standard_normal(a.shape), a.dtype), eng.caches)
+    before = [np.array(a) for a in jax.tree.leaves(eng.caches)]
+    start = eng.max_seq - eng.prefill_chunk + past
+    toks = jnp.asarray([[5, 6, 7, 8], [1, 2, 3, 4]], jnp.int32)
+    _, eng.caches = eng._step_fn(eng.params, toks, jnp.asarray([start, 3], jnp.int32),
+                                 eng.caches, jnp.asarray([True, False]))
+    for b, a in zip(before, (np.array(x) for x in jax.tree.leaves(eng.caches))):
+        assert np.array_equal(a[:, 1], b[:, 1])                    # slot left out
+        assert np.array_equal(a[:, 0, :start], b[:, 0, :start])    # rows before it
+        assert np.all(a[:, 0, start:] != b[:, 0, start:])          # its own rows
+
+
+@pytest.mark.parametrize("mode", [Mode.DENSE, Mode.LUT_INFER])
+def test_caches_after_many_steps_equal_the_select_merge_path(mode):
+    """The engine's caches and tokens after a run equal those of a step that
+    writes every row and then keeps the masked slots' rows by a select over
+    the whole cache."""
+    bundle, params = model = _model(mode)
+
+    def merged_step(params, tokens, cache_len, caches, mask):
+        logits, new = bundle.forward_step(
+            params, {"tokens": tokens, "cache_len": cache_len}, caches,
+            compute_dtype=jnp.float32)
+        return logits, jax.tree.map(
+            lambda o, n: jnp.where(mask.reshape((1, -1) + (1,) * (o.ndim - 2)), n, o),
+            caches, new)
+
+    def run(step=None):
+        eng = ServingEngine(*model, n_slots=3, max_seq=32, prefill_chunk=4,
+                            autotune_lut=False)
+        if step is not None:
+            eng._step_fn = jax.jit(step)
+        for i, prompt in enumerate(([3, 5, 7, 9, 11, 13], [2, 4], [6, 8, 10, 12, 14],
+                                    [1], [7, 7, 7, 7, 7, 7, 7, 7, 7])):
+            eng.submit(prompt, max_tokens=3 + 2 * i)
+        done = eng.run_until_done()
+        return ({r.rid: r.out_tokens for r in done},
+                [np.array(a) for a in jax.tree.leaves(eng.caches)])
+
+    tokens, caches = run()
+    ref_tokens, ref_caches = run(merged_step)
+    assert tokens == ref_tokens
+    assert _same(caches, ref_caches)
+
+
+def test_a_forward_that_loses_its_donated_caches_leaves_the_engine_usable():
+    """A step that hands back the caches it consumed leaves the engine no
+    rows to serve from: the next forward fails, the live requests retire as
+    "error", and the engine goes on from empty caches."""
+    eng = ServingEngine(*_model(Mode.DENSE), n_slots=2, max_seq=32, prefill_chunk=4,
+                        autotune_lut=False)
+    step = eng._step_fn
+
+    def stale(params, tokens, cache_len, caches, mask):
+        logits, _ = step(params, tokens, cache_len, caches, mask)
+        return logits, caches                   # deleted: the step consumed them
+    eng._step_fn = stale
+    eng.submit([1, 2, 3], max_tokens=4)
+    with pytest.raises(RuntimeError, match="deleted"):
+        eng.step()
+    assert [r.status for r in eng.finished] == ["error"] and not eng.has_work()
+    assert not any(np.any(np.array(leaf)) for leaf in jax.tree.leaves(eng.caches))
+
+    eng._step_fn = step
+    eng.submit([1, 2, 3], max_tokens=2)
+    assert eng.run_until_done()[-1].ok

@@ -229,3 +229,53 @@ def test_the_compiled_step_carries_each_scope(mode, tokens_per_row):
     bundle = build_model(reduce_arch(get_arch("qwen3_1p7b"), n_layers=2), mode)
     eng = _engine((bundle, bundle.init(jax.random.PRNGKey(0))))
     assert _scopes_in_step(eng, tokens_per_row) == set(SCOPES)
+
+
+# --- the compiled step writes the caches it is given, in place ----------------
+
+def _control_instructions(text):
+    """(opcode, dims, fused root opcode) of each instruction in the step's
+    control computations (entry, loop bodies and conditions, calls), not the
+    insides of fusions."""
+    comps, roots = {}, {}
+    for block in re.split(r"\n(?=\S)", text):
+        head = re.match(r"(ENTRY )?%?([\w.\-]+) \(", block)
+        if head:
+            comps[head.group(2)] = (bool(head.group(1)), block)
+            root = re.search(r"ROOT %?[\w.\-]+ = \S+ ([\w\-]+)\(", block)
+            roots[head.group(2)] = root.group(1) if root else None
+    control = set(re.findall(r"(?:body|condition|to_apply)=%?([\w.\-]+)", text))
+    for name, (entry, block) in comps.items():
+        if not (entry or name in control):
+            continue
+        for m in re.finditer(r"\n\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]*)\]\S* "
+                             r"([\w\-]+)\((.*)", block):
+            dims, op, rest = m.groups()
+            called = re.search(r"calls=%?([\w.\-]+)", rest)
+            yield op, tuple(int(d) for d in dims.split(",") if d), \
+                roots.get(called.group(1)) if called else None
+
+
+@pytest.mark.parametrize("mode", [Mode.DENSE, Mode.LUT_INFER])
+@pytest.mark.parametrize("tokens_per_row", [1, 4])
+def test_the_compiled_step_updates_each_cache_leaf_in_place(mode, tokens_per_row):
+    """The step aliases every cache leaf from input to output, and nothing in
+    it but an update in place makes a whole leaf: no copy, select or
+    broadcast of the cache, in any layout."""
+    bundle = build_model(reduce_arch(get_arch("qwen3_1p7b"), n_layers=3), mode)
+    eng = _engine((bundle, bundle.init(jax.random.PRNGKey(0))))
+    toks = jnp.zeros((eng.n_slots, tokens_per_row), jnp.int32)
+    text = eng._step_fn.lower(eng.params, toks, jnp.zeros((eng.n_slots,), jnp.int32),
+                              eng.caches, jnp.ones((eng.n_slots,), bool)).compile().as_text()
+    leaves = jax.tree.leaves(eng.caches)
+    cache_params = set(re.findall(r"parameter\((\d+)\)[^\n]*op_name=\"caches\[", text))
+    header = re.search(r"HloModule [^\n]*", text).group(0)
+    aliased = set(re.findall(r"\((\d+), \{\}, (?:may|must)-alias\)", header))
+    assert len(cache_params) == len(leaves) and aliased == cache_params
+    whole = {tuple(sorted(leaf.shape)) for leaf in leaves}
+    in_place = ("dynamic-update-slice", "scatter")
+    made = [(op, dims, root) for op, dims, root in _control_instructions(text)
+            if tuple(sorted(dims)) in whole
+            and op not in ("parameter", "get-tuple-element", "bitcast") + in_place
+            and not (op == "fusion" and root in in_place)]
+    assert not made, made
