@@ -40,7 +40,7 @@ def main(argv=None) -> int:
         return 2
     ctrl_seeds = {int(s) for s in args.control_seeds.split(",") if s}
     for fault in [f for f in args.faults.split(",") if f] or [None]:
-        hook = faults.FAULTS[fault] if fault else None
+        hook = faults.for_model(cell.model)[fault] if fault else None
         for seed in (int(s) for s in args.seeds.split(",")):
             _read(cell, seed, args, fault, hook, seed in ctrl_seeds and fault is None)
     return 0
@@ -52,7 +52,7 @@ def _read(cell, seed, args, fault, hook, with_control) -> None:
     run.T_START = time.time()
     sv = run.serve(cell, seed, args.seconds, trace=False, engine_hook=hook)
     reqs = check.sample(sv.finished, seed)
-    ref = check.Reference(cell.config, seed, sv.scale_shapes,
+    ref = check.Reference(cell.model, seed, sv.scale_shapes,
                           cell.settings["engine"]["max_seq"])
     t = time.perf_counter()
     prog, stats = check.check_served(ref, reqs, sv.kv_seqs, sv.kv_prog)

@@ -1,30 +1,33 @@
 """Whether what the timed engine served is correct.
 
-After the window has closed, the reference (`bench/reference/`) runs over
-two sets of sequences from the engine's own output, its weights made again
-from the seed:
+After the window has closed, the reference (`bench/reference/`, the
+configuration's `reference`) runs over two sets of sequences from the
+engine's own output, its weights made again from the seed:
 
 * served tokens: a sample of the requests the engine finished in the
   window (drawn from the seed, with the longest among them), each prompt
   with the tokens served for it. `gap` is the widest gap by which a served
   token's reference logit lies below the reference's best logit at that
   position (greedy serving).
-* the KV cache: the slots still live when the window closed, as many
-  positions as the engine had written for each. Per layer, each cached
-  key and value row is compared with the reference's: relative error
-  |program - reference| / |reference|.
+* the cache: the slots still live when the window closed, as many
+  positions as the engine had written for each. The family names the
+  cached leaves (`cache`: keys and values, or one latent leaf), each
+  (layers, slots, max_seq, rows, width); the reference's `layer` returns
+  the same leaves. Per layer, each cached row of each leaf is compared
+  with the reference's: relative error |program - reference| /
+  |reference|.
   - `kv_dense` is the largest of these in the layers served dense (layer
     0 of the LUT model, every layer of the dense one): no centroid code
     lies upstream of them, so they agree to float32 rounding.
-  - A LUT site's output is piecewise constant in its input: a key or
-    value row of a LUT layer equals the reference's to rounding unless
-    one of its codes was decided the other way. A row further off than
-    TAU is such a row. Float32 rounding decides a code the other way
-    where an encode lands within rounding of a tie; the change spreads to
-    later layers and positions, so the share of rows off grows with depth
-    by at most a few hundredths per layer (see `weights.BRANCH_SCALE`). A
-    wrong kernel, table or tiling at one kind of LUT site moves the input
-    of every later layer and raises that share by a tenth or more in one
+  - A LUT site's output is piecewise constant in its input: a cached row
+    of a LUT layer equals the reference's to rounding unless one of its
+    codes was decided the other way. A row further off than TAU is such
+    a row. Float32 rounding decides a code the other way where an encode
+    lands within rounding of a tie; the change spreads to later layers
+    and positions, so the share of rows off grows with depth by at most a
+    few hundredths per layer (see `weights.BRANCH_SCALE`). A wrong
+    kernel, table or tiling at one kind of LUT site moves the input of
+    every later layer and raises that share by a tenth or more in one
     layer; a fault confined to one site of one layer raises it by about
     half that (PERF.md).
     `kv1_share` is the share of layer 1's rows off (the first LUT layer:
@@ -33,13 +36,12 @@ from the seed:
 
 The control (`control`) is the reference itself at the next precision
 below the configuration's, read the same way against the reference: its
-greedy token at every position of the same sequences, and its keys and
-values.
+greedy token at every position of the same sequences, and its cached
+rows.
 """
 
 from __future__ import annotations
 
-import importlib
 from typing import Any, Callable
 
 import jax
@@ -56,10 +58,10 @@ TAU = 1e-3                   # a cached row further off than this counts
 HIGHEST = jax.lax.Precision.HIGHEST
 DESCRIBE = {
     "gap": "widest gap of a served token's logit below the reference's best",
-    "kv_dense": "largest relative error of a cached key or value row in a dense layer",
-    "kv1_share": f"share of layer-1 cached key and value rows off by more than {TAU}",
+    "kv_dense": "largest relative error of a cached row in a dense layer",
+    "kv1_share": f"share of layer-1 cached rows off by more than {TAU}",
     "kv_jump": f"largest rise from one layer to the next of the share of cached "
-               f"key and value rows off by more than {TAU}",
+               f"rows off by more than {TAU}",
 }
 
 
@@ -85,7 +87,8 @@ class SlotLog:
     """Which request each engine slot last held (a slot's cache keeps that
     request's keys and values until the slot is admitted again)."""
 
-    def __init__(self, engine):
+    def __init__(self, engine, family):
+        self.family = family
         self.last: dict[int, Any] = {}
         admit = engine._admit
 
@@ -99,8 +102,8 @@ class SlotLog:
 
     def cached(self, engine) -> tuple[list[list[int]], dict | None]:
         """Up to KV_SLOTS of the longest sequences the slots' caches hold,
-        and their cached keys and values as the engine wrote them:
-        {"k", "v"}: (layers, slots, max_seq, kv heads, head dim)."""
+        and their cached state as the engine wrote it, by the family's
+        leaves: {name: (layers, slots, max_seq, rows, width)}."""
         held = []
         for i, r in self.last.items():
             n = r.n_prefilled + max(len(r.out_tokens) - 1, 0) if r.prefill_done \
@@ -111,8 +114,9 @@ class SlotLog:
         if not held:
             return [], None
         idx = jnp.asarray([i for _, _, i, _ in held])
-        kv = {name: jnp.concatenate([seg[name][:, idx] for seg in engine.caches], axis=0)
-              for name in ("k", "v")}
+        segs = [self.family.cache(seg) for seg in engine.caches]
+        kv = {name: jnp.concatenate([seg[name][:, idx] for seg in segs], axis=0)
+              for name in segs[0]}
         return [seq for *_, seq in held], kv
 
 
@@ -120,19 +124,19 @@ class Reference:
     """The configuration's plain reference, run layer by layer over a few
     sequences padded to one length."""
 
-    def __init__(self, cfg: dict[str, Any], seed: int, scale_shapes: dict,
-                 pad_len: int):
-        self.cfg = cfg
-        self.mod = importlib.import_module(f"reference.{cfg['reference']}")
+    def __init__(self, model, seed: int, scale_shapes: dict, pad_len: int):
+        cfg = self.cfg = model.cfg
+        self.mod = model.reference
         self.key = weights.base_key(seed)
         self.pad_len = pad_len
         self.lut = weights.lut_layers(cfg)
+        self.specs = model.layers()
         self._gen = {
-            lut: jax.jit(lambda key, i, lut=lut: weights.gen_layer(
-                cfg, key, i, lut, scale_shapes))
-            for lut in set(self.lut)
+            (spec, lut): jax.jit(lambda key, i, spec=spec, lut=lut: weights.gen_layer(
+                spec, cfg, key, i, lut, scale_shapes))
+            for spec, lut in set(zip(self.specs, self.lut))
         }
-        self.g = jax.jit(lambda key: weights.gen_globals(cfg, key))(self.key)
+        self.g = jax.jit(model.gen_globals)(self.key)
         self._layer: dict = {}
         # ROWS rows of padding: a block of ROWS rows can start at any row
         self._final = jax.jit(lambda g, h: jnp.pad(self.mod.final_hidden(cfg, g, h),
@@ -147,8 +151,8 @@ class Reference:
     def forward(self, seqs: list[list[int]], precisions: tuple,
                 on_layer: Callable | None = None) -> dict:
         """Final normalised hidden states of each sequence at each precision
-        (padded by ROWS rows). `on_layer(i, j, {precision: (k, v)})` sees
-        each layer's keys and values of sequence j."""
+        (padded by ROWS rows). `on_layer(i, j, {precision: {name: rows}})`
+        sees each layer's cached leaves of sequence j."""
         hs = {}
         for p in precisions:
             hs[p] = []
@@ -156,13 +160,12 @@ class Reference:
                 toks = np.zeros((self.pad_len,), np.int32)
                 toks[: len(s)] = s
                 hs[p].append(self.mod.embed(self.g, jnp.asarray(toks)))
-        for i, lut in enumerate(self.lut):
-            w = self._gen[lut](self.key, i)
+        for i, key in enumerate(zip(self.specs, self.lut)):
+            w = self._gen[key](self.key, i)
             for j in range(len(seqs)):
                 kv = {}
                 for p in precisions:
-                    hs[p][j], k, v = self._layer_fn(p)(w, hs[p][j])
-                    kv[p] = (k, v)
+                    hs[p][j], kv[p] = self._layer_fn(p)(w, hs[p][j])
                 if on_layer is not None:
                     on_layer(i, j, kv)
             del w
@@ -277,7 +280,7 @@ def check_served(ref: Reference, reqs: list[dict[str, Any]],
                  kv_seqs: list[list[int]], kv_prog: dict | None) -> tuple[dict, KVStats]:
     """The numbers of a run: `gap` over the served tokens of `reqs`, and the
     KV numbers of the live slots' sequences `kv_seqs` against the program's
-    cached rows `kv_prog` ({"k", "v"}: (layers, slots, max_seq, kv, dh))."""
+    cached rows `kv_prog` ({name: (layers, slots, max_seq, rows, width)})."""
     seqs = [r["prompt"] + r["tokens"][:-1] for r in reqs]
     stats = KVStats(ref.lut)
 
@@ -286,8 +289,7 @@ def check_served(ref: Reference, reqs: list[dict[str, Any]],
         if j < 0:
             return
         n = len(kv_seqs[j])
-        k, v = kv[HIGHEST]
-        for name, r in (("k", k), ("v", v)):
+        for name, r in kv[HIGHEST].items():
             stats.add(i, _row_err(_pick(kv_prog[name], i, j), r, n), n)
 
     hf = ref.forward(seqs + kv_seqs, (HIGHEST,), on_layer)[HIGHEST]
@@ -304,7 +306,7 @@ def control(ref: Reference, reqs: list[dict[str, Any]], kv_seqs: list[list[int]]
             precision=jax.lax.Precision.HIGH) -> tuple[dict, KVStats]:
     """The same numbers for the reference at `precision` in the program's
     place: the gap of its greedy token at every position of the sequences,
-    and its keys and values against the reference's."""
+    and its cached rows against the reference's."""
     seqs = [r["prompt"] + r["tokens"][:-1] for r in reqs]
     stats = KVStats(ref.lut)
 
@@ -312,9 +314,8 @@ def control(ref: Reference, reqs: list[dict[str, Any]], kv_seqs: list[list[int]]
         if j < len(seqs):
             return
         n = len(kv_seqs[j - len(seqs)])
-        (k, v), (kc, vc) = kv[HIGHEST], kv[precision]
-        stats.add(i, _row_err(kc, k, n), n)
-        stats.add(i, _row_err(vc, v, n), n)
+        for name, r in kv[HIGHEST].items():
+            stats.add(i, _row_err(kv[precision][name], r, n), n)
 
     hf = ref.forward(seqs + kv_seqs, (HIGHEST, precision), on_layer)
     out = {}

@@ -3,13 +3,13 @@ of its device ops and its own host spans.
 
 The program puts each device op of its step under a named scope
 (`jax.named_scope`): `linear` (every linear site, LUT or dense),
-`attention`, `kv_write` (the cache writes and the engine's select-merge)
-and `lm_head`. XLA writes the scope path into each instruction's `op_name`
-metadata, and the TPU profiler keeps it as the `tf_op` stat of the op's
-event metadata in the device plane. `jax.profiler.ProfileData` does not
-expose event metadata, so `op_names` reads it from the serialized trace
-with a minimal protobuf reader. An op that XLA inserted without metadata
-(a layout `copy`) has no scope.
+`attention`, `kv_write` (the scatter of each forward's new rows into the
+cache the step was given) and `lm_head`. XLA writes the scope path into
+each instruction's `op_name` metadata, and the TPU profiler keeps it as
+the `tf_op` stat of the op's event metadata in the device plane.
+`jax.profiler.ProfileData` does not expose event metadata, so `op_names`
+reads it from the serialized trace with a minimal protobuf reader. An op
+that XLA inserted without metadata (a layout `copy`) has no scope.
 
 The program's host spans are `engine.*` (step, admit, prefill, decode,
 their `.forward` dispatch-to-ready intervals, sample) and `pump.*` (step,

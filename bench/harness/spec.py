@@ -1,9 +1,11 @@
 """`BENCHMARK.json` and the files it names, found by name.
 
-A configuration is `configs/<name>.json`, a traffic mix
-`traffic/<name>.json`, a cell's engine and load settings
-`cells/<workload name>.json`, and a per-layer metric `metrics/<name>.py`.
-Adding one of each is adding a file and an entry; nothing here changes.
+A configuration is `configs/<name>.json`, wired by its family module
+`families/<model_type>.py` and checked by `reference/<reference>.py`
+(`harness.family`); a traffic mix is `traffic/<name>.json`, a cell's
+engine and load settings `cells/<workload name>.json`, and a per-layer
+metric `metrics/<name>.py`. Adding one of each is adding a file and an
+entry; nothing here changes.
 """
 
 from __future__ import annotations
@@ -14,6 +16,8 @@ import json
 import pathlib
 import re
 from typing import Any, Callable
+
+from harness import family
 
 BENCH = pathlib.Path(__file__).resolve().parents[1]
 ROOT = BENCH.parent
@@ -67,11 +71,13 @@ class Cell:
     traffic_name: str
     traffic: dict[str, Any]         # traffic/<traffic>.json
     settings: dict[str, Any]        # cells/<name>.json: engine and load
+    model: family.Model             # the config with its family and reference
 
 
 class Benchmark:
-    """`BENCHMARK.json` at `root`; traffic and cell files under `data_dir`
-    (`bench/`). Tests hand in their own `data` and `data_dir`."""
+    """`BENCHMARK.json` at `root`; traffic, cell, family and reference files
+    under `data_dir` (`bench/`). Tests hand in their own `data` and
+    `data_dir`."""
 
     def __init__(self, root: pathlib.Path = ROOT, data: dict | None = None,
                  data_dir: pathlib.Path | None = None):
@@ -109,13 +115,15 @@ class Benchmark:
         w = self.workloads[name]
         cname = check_name(w["config"], "config")
         tname = check_name(w["traffic"], "traffic")
+        config = _json(self.root / self.configs[cname]["file"])
         return Cell(
             name=name, chips=int(w["chips"]),
             config_name=cname,
-            config=_json(self.root / self.configs[cname]["file"]),
+            config=config,
             traffic_name=tname,
             traffic=_json(self.data_dir / "traffic" / f"{tname}.json"),
             settings=_json(self.data_dir / "cells" / f"{name}.json"),
+            model=family.load(config, self.data_dir, self.root / "bench"),
         )
 
     def metrics_for(self, workload: str, *, trace: bool) -> list[Metric]:

@@ -39,7 +39,6 @@ from typing import Any
 import jax
 
 from harness import work as work_mod
-from harness.weights import site_dims
 
 SPAN = "bench."
 FORWARD_SPANS = ("bench.prefill", "bench.decode")
@@ -394,8 +393,8 @@ def step_share(ctx: Context, kind: str) -> float | None:
     measured = sum(f.measured_s for f in fs)
     if not fs or measured <= 0 or ctx.peaks is None:
         return None
-    least = sum(work_mod.forward_work(ctx.cfg, f.rows, ctx.scale_shapes).least_s(ctx.peaks)
-                for f in fs)
+    least = sum(work_mod.forward_work(ctx.cell.model, f.rows, ctx.scale_shapes)
+                .least_s(ctx.peaks) for f in fs)
     return 100.0 * least / measured
 
 
@@ -414,13 +413,14 @@ def kernel_share(ctx: Context, kind: str) -> float | None:
     least = dur = 0.0
     last: dict[str, Event] = {}
     charged: set[int] = set()
+    names = ctx.cell.model.lut_site_names()
     for ev, k in sorted(ctx.trace.ops, key=lambda x: x[0].start):
         if ev.lut is None or k != kind:
             last[ev.name] = ev
             continue
         n, d_in, d_out, kk, v = ev.lut
         # sites of one shape (q and o, k and v, gate and up) scale alike
-        name = next(nm for nm, dims in site_dims(ctx.cfg).items() if dims == (d_in, d_out))
+        name = names[d_in, d_out]
         parts = work_mod.lut_site_bytes(n, d_in, d_out, kk, v,
                                         work_mod.scale_elems(ctx.scale_shapes, name, d_out))
         moved = 0.0

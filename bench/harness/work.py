@@ -21,7 +21,7 @@ import dataclasses
 from typing import Any, Iterable
 
 from harness.peaks import Peaks
-from harness.weights import lut_layers, site_dims
+from harness.weights import Layer, lut_layers
 
 F32 = 4
 
@@ -92,39 +92,76 @@ class Row:
     sampled: bool
 
 
-def forward_work(cfg: dict[str, Any], rows: Iterable[Row],
-                 scale_shapes: dict | None = None) -> Work:
-    """The least work of one forward over its valid rows only: padding rows
-    and padded chunk positions count nothing; KV counts up to each row's
-    valid length; the lm-head counts only rows that sample a token; every
-    parameter byte is read once."""
+def sites_work(spec: Layer, lut: dict[str, Any] | None, t: int,
+               scale_shapes: dict | None = None) -> Work:
+    """The least work of one layer's linear sites (`spec`) on t token rows,
+    served as LUTs with the configuration's LUT settings `lut` (None:
+    dense). Inputs and outputs stay on chip in the least work: only the
+    sites' parameters cross HBM.
+
+    An expert-stacked site on t tokens serves t * top_k (token, expert)
+    pairs and reads the parameters of the experts they can hit,
+    min(experts, t * top_k), once. Its experts share one set of centroids,
+    so a LUT expert site encodes each token once."""
+    w = Work()
+    for s in spec.sites:
+        di, do, e = s.d_in, s.d_out, max(s.experts, 1)
+        pairs = t * s.top_k if s.experts else t
+        held = min(s.experts, pairs) if s.experts else 1
+        if lut is None or not s.lut:
+            w += Work(flops=2.0 * pairs * di * do, bytes=held * di * do * F32)
+            continue
+        k, v = lut["k"], lut["v"]
+        c = di // v
+        parts = lut_site_bytes(t, di, do, k, v, scale_elems(scale_shapes, s.name, do * e) // e)
+        w += Work(flops=2.0 * t * c * k * v, int8_ops=float(pairs) * c * do,
+                  bytes=parts["centroids"] + held * (parts["table"] + parts["scales"]))
+    return w
+
+
+@dataclasses.dataclass(frozen=True)
+class Attention:
+    """One layer's attention, as its family lays it out: the heads, the
+    width of a score and of a value per head, and the bytes the cache holds
+    for one position."""
+
+    heads: int
+    qk_dim: int
+    v_dim: int
+    cached_bytes: int
+
+
+def attention_work(rows: Iterable[Row], a: Attention) -> Work:
+    """One layer's attention over each row's keys, causal (position p
+    attends to p + 1 keys): a score of qk_dim and a weighted sum of v_dim
+    per head and key, and the cache read up to each row's valid length."""
+    w = Work()
+    for r in rows:
+        keys = r.new * r.start + r.new * (r.new + 1) // 2
+        w += Work(flops=2.0 * a.heads * (a.qk_dim + a.v_dim) * keys,
+                  bytes=a.cached_bytes * (r.start + r.new))
+    return w
+
+
+def forward_work(model, rows: Iterable[Row], scale_shapes: dict | None = None) -> Work:
+    """The least work of one forward of `model` (a `family.Model`) over its
+    valid rows only: padding rows and padded chunk positions count nothing;
+    each layer's sites (`sites_work`), norms and attention (the family's
+    `attention(cfg)`); KV up to each row's valid length; the lm-head only
+    for rows that sample a token; every parameter byte read once."""
     rows = [r for r in rows if r.new > 0]
     if not rows:
         return Work()
+    cfg = model.cfg
     t = sum(r.new for r in rows)
     n_samp = sum(1 for r in rows if r.sampled)
     d, vocab = cfg["hidden_size"], cfg["vocab_size"]
-    nh, nkv, dh = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
-                   cfg["head_dim"])
-    dims = site_dims(cfg)
-    lut = cfg.get("lut")
+    attn = model.family.attention(cfg)
     w = Work()
-    for is_lut in lut_layers(cfg):
-        for name, (di, do) in dims.items():
-            if is_lut:
-                # inputs and outputs stay on chip in the least work: only
-                # the site's parameters cross HBM
-                s = lut_site_work(t, di, do, lut["k"], lut["v"],
-                                  scale_elems(scale_shapes, name, do))
-                w += Work(s.flops, s.int8_ops, s.bytes - t * (di + do) * F32)
-            else:
-                w += Work(flops=2.0 * t * di * do, bytes=di * do * F32)
-        w += Work(bytes=(4 * d + 2 * dh) * F32)       # norms
-        for r in rows:
-            # causal: position p attends to p + 1 keys
-            keys = r.new * r.start + r.new * (r.new + 1) // 2
-            w += Work(flops=4.0 * nh * dh * keys,
-                      bytes=2 * nkv * dh * F32 * (r.start + r.new))
+    for spec, is_lut in zip(model.layers(), lut_layers(cfg)):
+        w += sites_work(spec, cfg.get("lut") if is_lut else None, t, scale_shapes)
+        w += Work(bytes=sum(n for _, n in spec.norms) * F32)
+        w += attention_work(rows, attn)
     w += Work(bytes=d * F32)                           # final norm
     if n_samp:
         w += Work(flops=2.0 * n_samp * d * vocab, bytes=vocab * d * F32)

@@ -1,6 +1,6 @@
-"""Share of the forwards' device time spent writing the KV cache: each
-layer's new keys and values, and the engine's select-merge of the
-whole cache (scope `kv_write`).
+"""Share of the forwards' device time spent writing the KV cache: the
+scatter of each forward's new keys and values, layer by layer, into the
+cache the step was given (scope `kv_write`).
 
 Read from the profiler trace by `harness.scopes`: leaf ops that start
 inside a forward, each under the innermost listed scope on its op_name.

@@ -63,8 +63,8 @@ def site(p: dict[str, Any], x, precision):
 
 def layer(cfg: dict[str, Any], w: dict[str, Any], h, precision):
     """One decoder layer over a whole sequence h (S, d). Returns the new h
-    and the layer's keys (after norm and RoPE) and values, (S, kv, dh)
-    each, as a KV cache holds them."""
+    and the layer's cached leaves as a KV cache holds them: {"k": keys
+    (after norm and RoPE), "v": values}, (S, kv, dh) each."""
     s = h.shape[0]
     eps = cfg["rms_norm_eps"]
     nh, nkv, dh = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
@@ -87,7 +87,8 @@ def layer(cfg: dict[str, Any], w: dict[str, Any], h, precision):
     m = rmsnorm(h, w["norm2"], eps)
     gate = site(w["gate"], m, precision)
     up = site(w["up"], m, precision)
-    return h + site(w["down"], jax.nn.silu(gate) * up, precision), cache_k, cache_v
+    return (h + site(w["down"], jax.nn.silu(gate) * up, precision),
+            {"k": cache_k, "v": cache_v})
 
 
 def embed(g: dict[str, Any], tokens):

@@ -81,13 +81,13 @@ def serve(cell, seed: int, seconds: float, *, trace: bool, engine_hook=None):
     cfg = cell.config
     jax.config.update("jax_default_matmul_precision", cfg["compute"]["matmul_precision"])
     compiles = tracing.CompileCounter()
-    sysm = system.build(cfg, cell.settings["engine"], seed, time.perf_counter)
+    sysm = system.build(cell.model, cell.settings["engine"], seed, time.perf_counter)
     if engine_hook is not None:
         engine_hook(sysm)
     plan = traffic.plan(cell.traffic, cell.settings["load"], seed=seed,
                         seconds=seconds, vocab=cfg["vocab_size"],
                         engine=cell.settings["engine"])
-    slots = check.SlotLog(sysm.engine)
+    slots = check.SlotLog(sysm.engine, cell.model.family)
     probe = tracing.Probe(sysm.engine, BENCH / ".trace") if trace else None
     lg = loadgen.LoadGen(sysm.pump, plan)
     setup_s = time.time() - T_START
@@ -163,7 +163,7 @@ def main(argv=None, *, require_chip: bool = True, engine_hook=None,
 
     t_ref = time.perf_counter()
     reqs = check.sample(sv.finished, args.seed)
-    ref = check.Reference(cfg, args.seed, sv.scale_shapes,
+    ref = check.Reference(cell.model, args.seed, sv.scale_shapes,
                           cell.settings["engine"]["max_seq"])
     numbers, kv_stats = check.check_served(ref, reqs, sv.kv_seqs, sv.kv_prog)
     del ref, sv.kv_prog

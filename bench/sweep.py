@@ -38,7 +38,7 @@ def main(argv=None) -> int:
         return 2
     cfg = cell.config
     jax.config.update("jax_default_matmul_precision", cfg["compute"]["matmul_precision"])
-    sysm = system.build(cfg, cell.settings["engine"], args.seed, time.perf_counter)
+    sysm = system.build(cell.model, cell.settings["engine"], args.seed, time.perf_counter)
     for rate in (float(r) for r in args.rates.split(",")):
         plan = traffic.plan(cell.traffic, {"rate_per_s": rate}, seed=args.seed,
                             seconds=args.seconds, vocab=cfg["vocab_size"],

@@ -10,8 +10,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from bench_paths import FIXTURES
-from harness import check, weights
+from bench_paths import BENCH, FIXTURES
+from harness import check, family
 
 from reference import qwen3
 
@@ -21,9 +21,10 @@ HIGHEST = jax.lax.Precision.HIGHEST
 @pytest.fixture(scope="module", params=["tiny-lut", "tiny-dense"])
 def ref(request):
     cfg = json.loads((FIXTURES / "configs" / f"{request.param}.json").read_text())
+    model = family.load(cfg, BENCH)
     # the kernel path's m-shared scale layout
-    shapes = {s: (1, 1, do) for s, (_, do) in weights.site_dims(cfg).items()}
-    return check.Reference(cfg, 2**31 + 11, shapes, pad_len=64)
+    shapes = {s.name: (1, 1, s.d_out) for s in model.layers()[1].sites}
+    return check.Reference(model, 2**31 + 11, shapes, pad_len=64)
 
 
 def _greedy(ref, prompt, n):
@@ -42,7 +43,8 @@ def _reference_kv(ref, seqs):
     rows = {"k": {}, "v": {}}
 
     def on_layer(i, j, kv):
-        rows["k"][i, j], rows["v"][i, j] = kv[HIGHEST]
+        for name, r in kv[HIGHEST].items():
+            rows[name][i, j] = r
     ref.forward(seqs, (HIGHEST,), on_layer)
     n_layers = len(ref.lut)
     return {name: jnp.stack([jnp.stack([r[i, j] for j in range(len(seqs))])

@@ -13,11 +13,14 @@ from harness import faults
 
 import run
 
+TINY = tiny_benchmark()
+DENSE_FAULTS = faults.for_model(TINY.cell("tiny-dense.chat").model)
+
 
 def _run(capsys, workload, *, trace=0, hook=None, seconds=2.0):
     rc = run.main(["--workload", workload, "--seed", str(2**31 + 5), "--seconds",
                    str(seconds), "--trace", str(trace)],
-                  require_chip=False, engine_hook=hook, bench=tiny_benchmark())
+                  require_chip=False, engine_hook=hook, bench=TINY)
     out = capsys.readouterr()
     assert rc == 0, out.err
     return json.loads(out.out.strip().splitlines()[-1]), out
@@ -52,9 +55,9 @@ def test_a_sound_run_is_correct(capsys):
     assert out.err.strip().splitlines()[-1].startswith("check:")
 
 
-@pytest.mark.parametrize("fault", sorted(faults.FAULTS))
+@pytest.mark.parametrize("fault", sorted(DENSE_FAULTS))
 def test_a_broken_timed_path_is_not_correct(capsys, fault):
-    res, out = _run(capsys, "tiny-dense.chat", hook=faults.FAULTS[fault])
+    res, out = _run(capsys, "tiny-dense.chat", hook=DENSE_FAULTS[fault])
     assert res["correct"] is False, out.err
     assert any(c["value"] is not None and c["value"] > c["limit"]
                for c in res["check"].values())
@@ -66,7 +69,7 @@ def test_a_broken_timed_path_is_not_correct(capsys, fault):
 @pytest.mark.parametrize("workload", ["tiny-lut.chat", "tiny-lut.batch"])
 @pytest.mark.parametrize("fault", ["state_unchanged", "token_altered", "drop_k", "drop_v"])
 def test_a_broken_lut_path_is_not_correct(capsys, fault, workload):
-    res, out = _run(capsys, workload, hook=faults.FAULTS[fault])
+    res, out = _run(capsys, workload, hook=faults.for_model(TINY.cell(workload).model)[fault])
     assert res["correct"] is False, out.err
     assert any(c["value"] is not None and c["value"] > c["limit"]
                for c in res["check"].values())

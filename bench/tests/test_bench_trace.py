@@ -88,14 +88,19 @@ def test_recorded_attribution_finds_every_lut_site_in_each_forward():
         assert {ev.lut[0] for ev in calls} == {n}
 
 
+def _kernel_scales(cell):
+    """The kernel path's m-shared table-scale layout of each LUT site."""
+    return {s.name: (1, 1, s.d_out) for s in cell.model.layers()[1].sites}
+
+
 def test_recorded_kernel_shares_stay_under_100():
     from bench_paths import ROOT
 
-    from harness import spec, weights
+    from harness import spec
     from harness.peaks import peaks
 
     cell = spec.Benchmark(ROOT).cell("qwen3_1p7b-lut.chat")
-    shapes = {s: (1, 1, do) for s, (_, do) in weights.site_dims(cell.config).items()}
+    shapes = _kernel_scales(cell)
     ctx = tracing.Context(cell=cell, cfg=cell.config, settings=cell.settings, stats={},
                           sent=[], t0=0.0, t_end=0.0, forwards=None,
                           trace=tracing.reduce(_recorded()), peaks=peaks("TPU v5 lite"),
@@ -125,11 +130,11 @@ def test_staged_bytes_are_the_vmem_operands():
 def _share_of(ops, cfg_cell="qwen3_1p7b-lut.chat"):
     from bench_paths import ROOT
 
-    from harness import spec, weights
+    from harness import spec
     from harness.peaks import peaks
 
     cell = spec.Benchmark(ROOT).cell(cfg_cell)
-    shapes = {s: (1, 1, do) for s, (_, do) in weights.site_dims(cell.config).items()}
+    shapes = _kernel_scales(cell)
     tr = Trace(ops, [Event("bench.window", 0.0, 1.0), Event("bench.decode", 0.0, 1.0)])
     ctx = tracing.Context(cell=cell, cfg=cell.config, settings=cell.settings, stats={},
                           sent=[], t0=0.0, t_end=0.0, forwards=None,

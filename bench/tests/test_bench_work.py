@@ -7,13 +7,15 @@ import bench_paths  # noqa: F401
 import jax
 import jax.numpy as jnp
 import pytest
-from bench_paths import FIXTURES, ROOT
-from harness import tracing, work
+from bench_paths import BENCH, FIXTURES, ROOT
+from harness import family, tracing, work
 from harness.peaks import peaks
-from harness.weights import site_dims
 
+QWEN3 = family.module(BENCH / "families" / "qwen3.py")
+site_dims = QWEN3.site_dims
 CFG = json.loads((ROOT / "bench/configs/qwen3_1p7b-lut.json").read_text())
 DENSE = json.loads((ROOT / "bench/configs/qwen3_1p7b-dense.json").read_text())
+LUT_MODEL, DENSE_MODEL = family.load(CFG, BENCH), family.load(DENSE, BENCH)
 V5E = peaks("TPU v5 lite")
 SITES = sorted(set(site_dims(CFG).values()))
 
@@ -89,14 +91,14 @@ def test_lut_work_never_above_an_implementation(n, d_in, d_out):
 def test_padding_rows_never_count():
     rows = [work.Row(100, 64, False), work.Row(0, 30, True)]
     padded = rows + [work.Row(0, 0, False)] * 6
-    for cfg in (CFG, DENSE):
-        assert work.forward_work(cfg, rows) == work.forward_work(cfg, padded)
-        assert work.forward_work(cfg, []) == work.Work()
+    for model in (LUT_MODEL, DENSE_MODEL):
+        assert work.forward_work(model, rows) == work.forward_work(model, padded)
+        assert work.forward_work(model, []) == work.Work()
 
 
 def test_forward_work_of_the_lut_and_dense_models_differ_only_at_sites():
     rows = [work.Row(37, 1, True)] * 3
-    lut, dense = work.forward_work(CFG, rows), work.forward_work(DENSE, rows)
+    lut, dense = work.forward_work(LUT_MODEL, rows), work.forward_work(DENSE_MODEL, rows)
     d_sites = sum(2.0 * 3 * di * do for di, do in site_dims(CFG).values()) * 27
     l_sites = sum(2.0 * 3 * (di // 32) * 16 * 32 for di, _ in site_dims(CFG).values()) * 27
     assert dense.flops - lut.flops == pytest.approx(d_sites - l_sites)
@@ -104,12 +106,12 @@ def test_forward_work_of_the_lut_and_dense_models_differ_only_at_sites():
 
 
 def test_kv_counts_to_the_valid_length_and_lm_head_only_where_sampled():
-    a = work.forward_work(DENSE, [work.Row(10, 1, True)])
-    b = work.forward_work(DENSE, [work.Row(20, 1, True)])
+    a = work.forward_work(DENSE_MODEL, [work.Row(10, 1, True)])
+    b = work.forward_work(DENSE_MODEL, [work.Row(20, 1, True)])
     per_key = 28 * 2 * 8 * 128 * 4
     assert b.bytes - a.bytes == pytest.approx(10 * per_key)
-    no_sample = work.forward_work(DENSE, [work.Row(0, 64, False)])
-    sample = work.forward_work(DENSE, [work.Row(0, 64, True)])
+    no_sample = work.forward_work(DENSE_MODEL, [work.Row(0, 64, False)])
+    sample = work.forward_work(DENSE_MODEL, [work.Row(0, 64, True)])
     assert sample.flops - no_sample.flops == 2.0 * 2048 * 151936
 
 
